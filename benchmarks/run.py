@@ -28,6 +28,9 @@ def main() -> None:
         os.environ["BENCH_SMOKE"] = "1"   # bench_concurrent: N subset
 
     from benchmarks.common import flush_csv
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     suites = [
         ("bench_page_count", "fig2a"),      # Fig 2(a): page-count sweep

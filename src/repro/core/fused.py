@@ -24,16 +24,16 @@ HBM round-trip of columns the predicate is about to throw away.  A
   are materialized only when at least one row survived.
 
 **Bit-identity contract.**  The canonical result of a predicated scan is
-defined per page: the float32 partial of
-``kernels/fused_agg.mask_and_reduce`` over the page's (1, P) padded
-block, then ``float(np.sum(partials, dtype=np.float64))`` per row group,
+defined per page: the float32 partial ``kernels/fused_agg.page_partial``
+computes over the page's padded (rows, 128) tiles, then
+``float(np.sum(partials, dtype=np.float64))`` per row group,
 then plan-order accumulation across row groups.  Reference execution
 (``mode="reference"``, or any row group whose shape the fused plan
 cannot take — cascade-coded operands, non-fusable aggregate inputs,
 misaligned page layouts) materializes everything through the unfused
-path and evaluates the SAME traced expression on the same page blocks,
-so fused and unfused results diff exactly, which CI enforces
-(tools/check_fused_identity.py).
+path and evaluates the SAME operations on the same page tiles, so fused
+and unfused results diff exactly wherever both lower through one compiler,
+which CI enforces on the CPU (tools/check_fused_identity.py).
 """
 
 from __future__ import annotations

@@ -21,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -94,15 +95,29 @@ def q6_fused_spec(mode: str = "fused") -> FusedSpec:
         mode=mode)
 
 
+_legacy_fused_consumes = 0
+_legacy_lock = threading.Lock()
+
+
+def legacy_fused_consume_count() -> int:
+    """Row groups a fused Q6 served through the unfused consume (process
+    total) — nonzero means fused was asked for and did not happen."""
+    return _legacy_fused_consumes
+
+
 def _q6_consume_fused(use_kernel: bool):
     """Sums the canonical per-RG fused partials in plan order.  Falls back
     to the legacy consume when a row group arrives without a fused result
-    (use_plan=False scanners, instance-patched decode paths)."""
+    (use_plan=False scanners, instance-patched decode paths), and counts
+    each such row group."""
     legacy = _q6_consume(use_kernel)
 
     def consume(acc, rg_index, cols):
+        global _legacy_fused_consumes
         res = cols.get(FUSED_KEY)
         if res is None:
+            with _legacy_lock:
+                _legacy_fused_consumes += 1
             return legacy(acc, rg_index, cols)
         return res.partial if acc is None else acc + res.partial
 

@@ -142,15 +142,28 @@ def generate_tables(sf: float = 0.01, seed: int = 0,
     return lineitem, orders
 
 
+def tpch_chunks(sf: float, seed: int = 0, include_strings: bool = True,
+                chunk_orders: int = 250_000) -> Iterator[tuple[Table, Table]]:
+    """The seeded ``(orders, lineitem)`` chunk stream ``write_tpch``
+    writes, in order.  Each lineitem chunk holds exactly the lines of its
+    orders chunk, so per-chunk query oracles add up to the whole table's."""
+    rng = np.random.default_rng(seed)
+    remaining = max(1, int(ORDERS_ROWS_PER_SF * sf))
+    key = 1
+    while remaining > 0:
+        k = min(chunk_orders, remaining)
+        oc = _gen_orders_chunk(rng, key, k, include_strings)
+        yield oc, _gen_lineitem_chunk(rng, oc, include_strings)
+        key += k
+        remaining -= k
+
+
 def write_tpch(out_dir: str, sf: float, config: FileConfig, seed: int = 0,
                include_strings: bool = True, threads: int = 4,
                chunk_orders: int = 250_000
                ) -> dict[str, FileMeta]:
     """Streamed generation to ``out_dir/{lineitem,orders}.tab``."""
     os.makedirs(out_dir, exist_ok=True)
-    rng = np.random.default_rng(seed)
-    n_orders = max(1, int(ORDERS_ROWS_PER_SF * sf))
-
     lpath = os.path.join(out_dir, "lineitem.tab")
     opath = os.path.join(out_dir, "orders.tab")
     lw = TabFileWriter(lpath, config, threads).begin(
@@ -174,16 +187,10 @@ def write_tpch(out_dir: str, sf: float, config: FileConfig, seed: int = 0,
             writer.write_row_group(pending[0] if len(pending) == 1
                                    else Table.concat(pending))
 
-    lchunks, ochunks = [], []
-    key = 1
-    remaining = n_orders
-    while remaining > 0:
-        k = min(chunk_orders, remaining)
-        oc = _gen_orders_chunk(rng, key, k, include_strings)
+    ochunks, lchunks = [], []
+    for oc, lc in tpch_chunks(sf, seed, include_strings, chunk_orders):
         ochunks.append(oc)
-        lchunks.append(_gen_lineitem_chunk(rng, oc, include_strings))
-        key += k
-        remaining -= k
+        lchunks.append(lc)
     rg_stream(ow, iter(ochunks))
     rg_stream(lw, iter(lchunks))
     ometa = ow.finish()

@@ -5,7 +5,9 @@ and counts (core/compression.py).  Decompression = two static-width unpacks
 + run expansion, i.e. exactly the vector primitives the VPU is good at —
 this is the TPU-idiomatic replacement for GPU Snappy kernels (DESIGN.md §2).
 
-grid = (num_pages, num_tiles), tiled like rle_decode.
+grid = (num_pages,).  The packed run words ride in SMEM; each run's value
+and count unpack as scalars (``width`` bit reads of one group's words) and
+the run expansion paints rows like rle_decode.
 """
 
 from __future__ import annotations
@@ -16,19 +18,32 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import (count_launch, expand_runs_tile,
-                                  interpret_default, unpack_words_static)
+from repro.kernels.common import (LANES, ROW, cdiv, count_launch,
+                                  interpret_default, pad_axis, smem_pages)
+from repro.kernels.rle_decode import paint_runs
 
 TILE = 1024
 
 
+def _scalar_unpack(words_ref, k, width: int):
+    """Value ``k`` of a bit-transposed stream, as a uint32 scalar."""
+    k = jnp.asarray(k, jnp.int32)
+    base = (k // LANES) * width
+    j = (k % LANES).astype(jnp.uint32)
+    v = jnp.uint32(0)
+    for b in range(width):
+        w = words_ref[base + b].astype(jnp.uint32)
+        v = v | (((w >> j) & jnp.uint32(1)) << jnp.uint32(b))
+    return v
+
+
 def _kernel(val_words_ref, cnt_words_ref, out_ref, *,
-            value_width: int, count_width: int, n_runs: int):
-    vals = unpack_words_static(val_words_ref[0, :], value_width)[:n_runs]
-    counts = unpack_words_static(cnt_words_ref[0, :], count_width)[:n_runs]
-    tile_start = pl.program_id(1) * TILE
-    out_ref[0, :] = expand_runs_tile(vals, counts.astype(jnp.int32),
-                                     tile_start, TILE)
+            value_width: int, count_width: int, n_runs: int, n_rows: int):
+    paint_runs(
+        out_ref, n_runs, n_rows,
+        lambda k: _scalar_unpack(val_words_ref, k, value_width),
+        lambda k: _scalar_unpack(cnt_words_ref, k,
+                                 count_width).astype(jnp.int32))
 
 
 def cascade_decode_pages(val_words: jnp.ndarray, cnt_words: jnp.ndarray, *,
@@ -59,17 +74,21 @@ def _cascade_decode_pages_jit(val_words, cnt_words, *,
                               interpret: bool) -> jnp.ndarray:
     n_pages = val_words.shape[0]
     assert n_out % TILE == 0
-    n_tiles = n_out // TILE
-    wv, wc = val_words.shape[1], cnt_words.shape[1]
-    return pl.pallas_call(
+    n_rows = n_out // ROW
+
+    # one SMEM row per page holding every group of the run table
+    vw, vspec = smem_pages(pad_axis(val_words, 1,
+                                    cdiv(n_runs, LANES) * value_width))
+    cw, cspec = smem_pages(pad_axis(cnt_words, 1,
+                                    cdiv(n_runs, LANES) * count_width))
+    out = pl.pallas_call(
         functools.partial(_kernel, value_width=value_width,
-                          count_width=count_width, n_runs=n_runs),
-        grid=(n_pages, n_tiles),
-        in_specs=[
-            pl.BlockSpec((1, wv), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, wc), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, TILE), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n_pages, n_out), jnp.uint32),
+                          count_width=count_width, n_runs=n_runs,
+                          n_rows=n_rows),
+        grid=(n_pages,),
+        in_specs=[vspec, cspec],
+        out_specs=pl.BlockSpec((1, n_rows, ROW), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pages, n_rows, ROW), jnp.uint32),
         interpret=interpret,
-    )(val_words, cnt_words)
+    )(vw, cw)
+    return out.reshape(n_pages, n_out)

@@ -1,8 +1,11 @@
-"""Pallas kernel: RLE_DICTIONARY page decode (unpack codes + gather).
+"""Pallas kernel: RLE_DICTIONARY page decode (unpack codes + lookup).
 
-grid = (num_pages,).  The dictionary itself lives in VMEM for the whole
-call (one dictionary per column chunk); ops.py falls back to the host path
-when a dictionary would not fit VMEM.
+grid = (num_pages,).  Each page is a (rows, 128) block (kernels/common.py);
+the kernel walks it in CHUNK_ROWS-row steps: unpack the codes with one lane
+gather per bit, then look them up with compare-and-select over 128-entry
+dictionary rows (the TPU lowers only in-row lane gathers, so a dictionary
+of D entries costs ceil(D/128) gathers per tile).  The dictionary stays in
+VMEM for the whole call (one dictionary per column chunk).
 """
 
 from __future__ import annotations
@@ -12,23 +15,58 @@ import threading
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 from repro.core.lru import ByteCappedLRU
-from repro.kernels.common import (count_launch, interpret_default,
-                                  unpack_words_static)
+from repro.kernels.common import (CHUNK_ROWS, LANES, ROW, count_launch,
+                                  dict_rows, from_dict_bits,
+                                  interpret_default, lookup_rows,
+                                  padded_rows, unpack_rows, words_to_rows)
 
 
-def _kernel(words_ref, dict_ref, out_ref, *, width: int):
-    codes = unpack_words_static(words_ref[0, :], width).astype(jnp.int32)
-    codes = jnp.clip(codes, 0, dict_ref.shape[0] - 1)
-    out_ref[0, :] = dict_ref[:][codes]
+def _kernel(words_ref, dict_ref, out_ref, *, width: int, d: int,
+            n_rows: int):
+    def body(i, carry):
+        rows = pl.ds(pl.multiple_of(i * CHUNK_ROWS, CHUNK_ROWS), CHUNK_ROWS)
+        codes = unpack_rows(words_ref[0, rows, :], width).astype(jnp.int32)
+        codes = jnp.clip(codes, 0, d - 1)
+        out_ref[0, rows, :] = lookup_rows(codes, dict_ref)
+        return carry
+
+    lax.fori_loop(0, n_rows // CHUNK_ROWS, body, 0)
+
+
+def _decode(words, drows, *, width: int, d: int, shared: bool,
+            interpret: bool):
+    """Shared pallas_call: drows is (n_chunks, 128) (one dictionary) or
+    (n_pages, n_chunks, 128) (one per page).  Returns int32 bit patterns
+    (n_pages, n_vals)."""
+    n_pages, n_words = words.shape
+    n_vals = (n_words // width) * LANES
+    n_rows = padded_rows(n_vals)
+    rows = words_to_rows(words, width, n_rows)
+    if shared:
+        dspec = pl.BlockSpec(drows.shape, lambda i: (0, 0))
+    else:
+        dspec = pl.BlockSpec((1, *drows.shape[1:]), lambda i: (i, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_kernel, width=width, d=d, n_rows=n_rows),
+        grid=(n_pages,),
+        in_specs=[pl.BlockSpec((1, n_rows, ROW), lambda i: (i, 0, 0)),
+                  dspec],
+        out_specs=pl.BlockSpec((1, n_rows, ROW), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pages, n_rows, ROW), jnp.int32),
+        interpret=interpret,
+    )(rows, drows)
+    return out.reshape(n_pages, n_rows * ROW)[:, :n_vals]
 
 
 def dict_decode_pages(words: jnp.ndarray, dictionary: jnp.ndarray, *,
                       width: int, interpret: bool | None = None
                       ) -> jnp.ndarray:
-    """words: (n_pages, G*width) uint32; dictionary: (D,) int32/uint32/f32.
+    """words: (n_pages, G*width) uint32; dictionary: (D,) int32/uint32/f32
+    (or uint8).
 
     Returns (n_pages, G*32) of dictionary.dtype.
     """
@@ -42,26 +80,9 @@ def dict_decode_pages(words: jnp.ndarray, dictionary: jnp.ndarray, *,
 @functools.partial(jax.jit, static_argnames=("width", "interpret"))
 def _dict_decode_pages_jit(words, dictionary, *, width: int,
                            interpret: bool) -> jnp.ndarray:
-    n_pages, n_words = words.shape
-    n_vals = (n_words // width) * 32
-    d = dictionary.shape[0]
-    return pl.pallas_call(
-        functools.partial(_kernel, width=width),
-        grid=(n_pages,),
-        in_specs=[
-            pl.BlockSpec((1, n_words), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, n_vals), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pages, n_vals), dictionary.dtype),
-        interpret=interpret,
-    )(words, dictionary)
-
-
-def _kernel_multi(words_ref, dict_ref, out_ref, *, width: int):
-    codes = unpack_words_static(words_ref[0, :], width).astype(jnp.int32)
-    codes = jnp.clip(codes, 0, dict_ref.shape[1] - 1)
-    out_ref[0, :] = dict_ref[0, :][codes]
+    out = _decode(words, dict_rows(dictionary), width=width,
+                  d=dictionary.shape[0], shared=True, interpret=interpret)
+    return from_dict_bits(out, dictionary.dtype)
 
 
 def dict_decode_pages_multi(words: jnp.ndarray, dictionaries: jnp.ndarray, *,
@@ -84,21 +105,10 @@ def dict_decode_pages_multi(words: jnp.ndarray, dictionaries: jnp.ndarray, *,
 @functools.partial(jax.jit, static_argnames=("width", "interpret"))
 def _dict_decode_pages_multi_jit(words, dictionaries, *, width: int,
                                  interpret: bool) -> jnp.ndarray:
-    n_pages, n_words = words.shape
-    n_vals = (n_words // width) * 32
-    d = dictionaries.shape[1]
-    return pl.pallas_call(
-        functools.partial(_kernel_multi, width=width),
-        grid=(n_pages,),
-        in_specs=[
-            pl.BlockSpec((1, n_words), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n_vals), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pages, n_vals),
-                                       dictionaries.dtype),
-        interpret=interpret,
-    )(words, dictionaries)
+    out = _decode(words, dict_rows(dictionaries), width=width,
+                  d=dictionaries.shape[1], shared=False,
+                  interpret=interpret)
+    return from_dict_bits(out, dictionaries.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -3,7 +3,8 @@
 Beyond-paper: the paper overlaps the *reading* stage with query operators;
 fusing the Q6 filter+aggregate into one kernel removes a full HBM round-trip
 of the filtered columns.  grid = (num_tiles,) over the decoded column
-stream; each tile emits one partial sum, reduced outside.
+stream — (64, 128) tiles — and each tile emits one partial sum,
+reduced outside.
 
 Predicate (Q6 shape):  lo <= key < hi  AND  dlo <= disc <= dhi  AND
 qty < qmax;  aggregate: sum(price * disc).
@@ -18,20 +19,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import interpret_default
+from repro.kernels.common import ROW, interpret_default
 
 TILE = 8192
+TILE_ROWS = TILE // ROW
 
 
 def _kernel(key_ref, qty_ref, disc_ref, price_ref, out_ref, *,
             lo: int, hi: int, dlo: float, dhi: float, qmax: float):
-    key = key_ref[0, :]
-    disc = disc_ref[0, :]
+    key = key_ref[...]
+    disc = disc_ref[...]
     mask = ((key >= lo) & (key < hi)
             & (disc >= dlo) & (disc <= dhi)
-            & (qty_ref[0, :] < qmax))
-    out_ref[0, 0] = jnp.sum(
-        jnp.where(mask, price_ref[0, :] * disc, jnp.float32(0)))
+            & (qty_ref[...] < qmax))
+    part = jnp.sum(jnp.where(mask, price_ref[...] * disc, jnp.float32(0)))
+    out_ref[0] = jnp.full((1, ROW), part, jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -49,19 +51,14 @@ def filter_agg_q6(key: jnp.ndarray, qty: jnp.ndarray, disc: jnp.ndarray,
     n = key.shape[0]
     assert n % TILE == 0, "pad inputs to TILE"
     n_tiles = n // TILE
+    tile = pl.BlockSpec((TILE_ROWS, ROW), lambda i: (i, 0))
     partials = pl.pallas_call(
         functools.partial(_kernel, lo=lo, hi=hi, dlo=dlo, dhi=dhi,
                           qmax=qmax),
         grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, TILE), lambda i: (0, i)),
-            pl.BlockSpec((1, TILE), lambda i: (0, i)),
-            pl.BlockSpec((1, TILE), lambda i: (0, i)),
-            pl.BlockSpec((1, TILE), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, n_tiles), jnp.float32),
+        in_specs=[tile, tile, tile, tile],
+        out_specs=pl.BlockSpec((1, 1, ROW), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, 1, ROW), jnp.float32),
         interpret=interpret,
-    )(key.reshape(1, n), qty.reshape(1, n), disc.reshape(1, n),
-      price.reshape(1, n))
-    return jnp.sum(partials)
+    )(*(x.reshape(n // ROW, ROW) for x in (key, qty, disc, price)))
+    return jnp.sum(partials[:, 0, 0])

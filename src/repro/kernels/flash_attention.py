@@ -44,10 +44,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, kv_block: int, scale: float,
 
     def body(ki, carry):
         m, l, acc = carry
-        kblk = pl.load(k_ref, (0, pl.dslice(ki * kv_block, kv_block),
-                               slice(None))).astype(jnp.float32)
-        vblk = pl.load(v_ref, (0, pl.dslice(ki * kv_block, kv_block),
-                               slice(None))).astype(jnp.float32)
+        kv_rows = pl.ds(ki * kv_block, kv_block)
+        kblk = k_ref[0, kv_rows, :].astype(jnp.float32)
+        vblk = v_ref[0, kv_rows, :].astype(jnp.float32)
         s = q @ kblk.T                                     # (qb, kv_block)
         if cap:
             s = cap * jnp.tanh(s / cap)

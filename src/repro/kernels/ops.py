@@ -16,6 +16,8 @@ Dispatch rules (documented fallbacks, DESIGN.md §2):
 from __future__ import annotations
 
 import dataclasses
+import threading
+from collections import Counter
 from collections.abc import Sequence
 
 import jax.numpy as jnp
@@ -37,6 +39,18 @@ from repro.kernels.rle_decode import rle_decode_pages
 _INT32_SAFE = 2 ** 30  # conservative: keeps deltas within int32 too
 _RLE_MAX_RUNS = 8192   # beyond this the host path wins (and Insight 3 would
                        # not have selected RLE anyway)
+
+
+# Columns a use_kernels decode sent to the host decoders (process-wide
+# counts by column name).  DESIGN.md §2.2 allows it for strings, float64
+# and out-of-range int64; a numeric TPC-H scan column should never land here.
+_host_fallbacks: Counter = Counter()
+_fallback_lock = threading.Lock()
+
+
+def host_fallback_counts() -> Counter:
+    with _fallback_lock:
+        return Counter(_host_fallbacks)
 
 
 @dataclasses.dataclass
@@ -354,6 +368,9 @@ def decode_chunk(chunk: ChunkMeta, field: Field, raw: bytes,
         if dec is not None:
             arr = dec(chunk, field, dict_payload, pages)
     on_device = arr is not None
+    if use_kernels and arr is None:
+        with _fallback_lock:
+            _host_fallbacks[chunk.name] += 1
     if arr is None:  # host fallback
         dictionary = None
         if dict_payload is not None:

@@ -38,13 +38,19 @@ ICI_LINK_BW = 50e9              # B/s per link
 def scan_devices(n: int | None = None) -> list:
     """Devices for the distributed scan executor (dataset/executor.py).
 
-    ``None`` → every jax device.  ``n`` → the first n devices, cycling
-    when n exceeds what the platform exposes (so devices=4 still runs —
-    and still reduces deterministically — on a 1-device host; real
-    speedup needs real devices or XLA_FLAGS host-platform emulation).
+    ``None`` → every jax device.  ``n`` → the first n devices.  On an
+    accelerator, asking for more devices than exist raises: a scan that
+    reports ``devices=4`` ran on four chips.  Only the CPU backend cycles
+    (so devices=4 still runs — and still reduces deterministically — in a
+    one-device test process; real parallelism there needs
+    ``XLA_FLAGS=--xla_force_host_platform_device_count``).
     """
     devs = list(jax.devices())
     if n is None:
         return devs
     n = max(1, int(n))
+    if n > len(devs) and jax.default_backend() != "cpu":
+        raise ValueError(
+            f"asked for {n} scan devices; the {jax.default_backend()} "
+            f"backend has {len(devs)}")
     return [devs[i % len(devs)] for i in range(n)]

@@ -96,6 +96,15 @@ def test_scan_devices_cycles_on_small_hosts():
     assert scan_devices(1) == [devs[0]]
 
 
+def test_scan_devices_refuses_missing_accelerators(monkeypatch):
+    import jax
+    n = len(jax.devices())
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert len(scan_devices(n)) == n
+    with pytest.raises(ValueError, match="backend has"):
+        scan_devices(n + 1)
+
+
 # -- tree reduce ------------------------------------------------------------
 
 def test_tree_reduce_pairing_depends_only_on_length():

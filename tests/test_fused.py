@@ -234,6 +234,22 @@ def test_q6_fused_bitwise_and_oracle(q6_file):
     assert got_f == pytest.approx(ref, rel=1e-5)
 
 
+def test_q6_fused_counts_legacy_consumes(q6_file):
+    """A plan-less scanner cannot fuse: every row group it delivers goes
+    through the unfused consume, and the counter says so."""
+    from repro.core.query import legacy_fused_consume_count
+    path, meta, line = q6_file
+    n0 = legacy_fused_consume_count()
+    got, _ = q6(Scanner(path, Q6_COLUMNS, decode_backend="host",
+                        use_plan=False), fused=True, overlapped=False)
+    assert legacy_fused_consume_count() - n0 == len(meta.row_groups)
+    ref = q6_reference({c: np.asarray(line[c]) for c in Q6_COLUMNS})
+    assert got == pytest.approx(ref, rel=1e-5)
+    n1 = legacy_fused_consume_count()
+    q6(Scanner(path, Q6_COLUMNS, decode_backend="pallas"), fused=True)
+    assert legacy_fused_consume_count() == n1
+
+
 def test_zone_maps_skip_pages(tmp_path):
     """A predicate on a sorted column must skip whole pages via the
     writer's per-page vmin/vmax stamps — before any arena byte exists."""

@@ -84,6 +84,27 @@ def test_q6_kernel_path(tpch_files):
     assert abs(got - ref) / max(1.0, abs(ref)) < 1e-4
 
 
+def test_pallas_scan_keeps_q6_q12_columns_on_device(tpch_files):
+    """On the pallas backend no Q6/Q12 column takes the host fallback;
+    a string column does, and is counted."""
+    from repro.kernels.ops import host_fallback_counts
+    metas, _, _ = tpch_files
+    cols = sorted(set(Q6_COLUMNS) | set(Q12_LINEITEM_COLUMNS))
+    before = host_fallback_counts()
+    for path, names in ((metas["lineitem_path"], cols),
+                        (metas["orders_path"], Q12_ORDERS_COLUMNS)):
+        sc = open_scanner(path, columns=list(names),
+                          decode_backend="pallas")
+        for _, decoded in sc.scan():
+            assert all(res.on_device for res in decoded.values())
+    assert host_fallback_counts() - before == {}
+    sc = open_scanner(metas["lineitem_path"], columns=["l_comment"],
+                      decode_backend="pallas")
+    for _ in sc.scan():
+        pass
+    assert (host_fallback_counts() - before)["l_comment"] >= 1
+
+
 def test_q6_pruning_safe(tpch_files):
     metas, line, _ = tpch_files
     sc1 = open_scanner(metas["lineitem_path"], columns=Q6_COLUMNS,
