@@ -1,0 +1,12 @@
+"""Host-clock consume time per query: the program's
+ScanMetrics.consume_seconds (the query operators over decoded row
+groups) summed over a query's scan reports, mean over the window's
+queries, in ms."""
+
+
+def read(run):
+    if not run.records:
+        return None
+    total = sum(rep.metrics.consume_seconds for r in run.records
+                for rep in r.reports)
+    return total / len(run.records) * 1e3
