@@ -42,10 +42,12 @@ import time
 from collections import OrderedDict
 from collections.abc import Callable, Sequence
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bitpack
 from repro.core import fused as fused_mod
+from repro.core import trace
 from repro.core.compression import (Codec, cascade_manifest,
                                     chunk_decompress_memo, decompress,
                                     verify_page)
@@ -652,9 +654,14 @@ class DecodePlanner:
         if ctx.leases:
             # flush before returning arenas: a pooled buffer may be aliased
             # by in-flight device computation until results materialize
+            tr = trace.active()
+            t0 = time.perf_counter() if tr is not None else 0.0
             for res in ctx.out.values():
                 if res.on_device:
                     res.array.block_until_ready()
+            if tr is not None:
+                tr.complete("device_wait", "device", t0,
+                            time.perf_counter(), site="finalize")
             for buf in ctx.leases:
                 self._arena_pool.give(buf)
         if ctx.fused_result is not None:
@@ -828,17 +835,24 @@ class DecodePlanner:
     def _execute_group_pallas(self, group: DecodeGroup,
                               slots: list[PageSlot], rg, payloads,
                               per_col_parts, leases) -> None:
+        """Pack the group's inputs on the host, ``stage`` them to the
+        device, launch its kernel."""
+        tr = trace.active()
+        stage = functools.partial(
+            _stage, tr, time.perf_counter() if tr is not None else 0.0)
         enc = group.encoding
         if enc == Encoding.RLE_DICTIONARY:
             batch = self._dict_group_pallas(group, slots, rg, payloads,
-                                            leases)
+                                            leases, stage)
         elif enc == Encoding.DELTA_BINARY_PACKED:
-            batch = self._delta_group_pallas(group, slots, rg, payloads)
+            batch = self._delta_group_pallas(group, slots, rg, payloads,
+                                             stage)
         elif enc == Encoding.RLE:
-            batch = self._rle_group_pallas(group, slots, rg, payloads)
+            batch = self._rle_group_pallas(group, slots, rg, payloads,
+                                           stage)
         else:
             batch = self._bss_group_pallas(group, slots, rg, payloads,
-                                           leases)
+                                           leases, stage)
         self._scatter_batch(batch, slots, per_col_parts)
 
     @staticmethod
@@ -858,7 +872,7 @@ class DecodePlanner:
                 ops._compact(batch[i:j], counts)
             i = j
 
-    def _dict_group_pallas(self, group, slots, rg, payloads, leases):
+    def _dict_group_pallas(self, group, slots, rg, payloads, leases, stage):
         width = group.key[2]
         w_arena = max(
             -(-rg.column(s.column).pages[s.page_index].uncompressed_size
@@ -874,7 +888,7 @@ class DecodePlanner:
                                                           payloads)
         if len(dicts) == 1:   # single-column group: no dict duplication
             return ops.decode_dict_group_shared(
-                arena, next(iter(dicts.values())).device, width)
+                *stage(arena, next(iter(dicts.values()))), width)
         d_max = max(d.host.shape[0] for d in dicts.values())
         dtype = next(iter(dicts.values())).host.dtype
         dict_arena, dbuf = self._arena_pool.take((len(slots), d_max), dtype)
@@ -882,7 +896,7 @@ class DecodePlanner:
         for row, s in enumerate(slots):
             d = dicts[s.column].host
             dict_arena[row, :d.shape[0]] = d
-        return ops.decode_dict_group(arena, dict_arena, width)
+        return ops.decode_dict_group(*stage(arena, dict_arena), width)
 
     def _device_dictionary(self, rg, name: str, payloads
                            ) -> dict_decode.CachedDictionary:
@@ -908,14 +922,14 @@ class DecodePlanner:
         return dict_decode.dict_cache_put(
             key, np.ascontiguousarray(dictionary))
 
-    def _delta_group_pallas(self, group, slots, rg, payloads):
+    def _delta_group_pallas(self, group, slots, rg, payloads, stage):
         n_blocks = group.key[2]
         mans = [self._manifest(rg, s, payloads) for s in slots]
         pls = [self._payload_bytes(payloads, s) for s in slots]
-        arrays = ops.delta_group_arrays(mans, pls, n_blocks)
+        arrays = stage(*ops.delta_group_arrays(mans, pls, n_blocks))
         return ops.decode_delta_group(*arrays, n_blocks=n_blocks)
 
-    def _rle_group_pallas(self, group, slots, rg, payloads):
+    def _rle_group_pallas(self, group, slots, rg, payloads, stage):
         n_out, vdt_name = group.key[2], group.key[3]
         vdt = np.dtype(vdt_name)
         runs = []
@@ -927,10 +941,10 @@ class DecodePlanner:
                 np.frombuffer(p, dtype=vdt, count=r).astype(np.int32),
                 np.frombuffer(p, dtype=np.int32, count=r,
                               offset=r * vdt.itemsize)))
-        vals, counts = ops.rle_group_arrays(runs)
+        vals, counts = stage(*ops.rle_group_arrays(runs))
         return ops.decode_rle_group(vals, counts, n_out=n_out)
 
-    def _bss_group_pallas(self, group, slots, rg, payloads, leases):
+    def _bss_group_pallas(self, group, slots, rg, payloads, leases, stage):
         stride = group.key[2]
         arena, buf = self._arena_pool.take((len(slots), 4 * stride),
                                            np.uint32)
@@ -947,7 +961,7 @@ class DecodePlanner:
                 for plane in range(4):
                     arena[row, plane * stride:plane * stride + s_words] = \
                         words[plane * s_words:(plane + 1) * s_words]
-        return ops.decode_bss_group(arena, stride)
+        return ops.decode_bss_group(*stage(arena), stride)
 
     # -- host group execution ---------------------------------------------
 
@@ -1094,6 +1108,29 @@ class DecodePlanner:
 
 _PLANNER_CACHE: "OrderedDict[tuple, DecodePlanner]" = OrderedDict()
 _PLANNER_CACHE_MAX = 64
+
+
+def _stage(tr, t0: float, *packed) -> tuple:
+    """Host→device staging of one group's packed inputs: arrays by
+    transfer, cached dictionaries through the cache (staged once).  With
+    the recorder on, the packing since ``t0`` is a ``pack`` span and the
+    transfer a ``stage`` span, each with the bytes it covers."""
+    if tr is None:
+        return tuple(_on_device(a) for a in packed)
+    t1 = time.perf_counter()
+    tr.complete("pack", "decode", t0, t1,
+                bytes=sum(a.nbytes for a in packed))
+    moved = sum(a.nbytes for a in packed
+                if not getattr(a, "on_device", False))
+    out = tuple(_on_device(a) for a in packed)
+    tr.complete("stage", "decode", t1, time.perf_counter(), bytes=moved)
+    return out
+
+
+def _on_device(a):
+    if isinstance(a, dict_decode.CachedDictionary):
+        return a.device
+    return jnp.asarray(a)
 
 
 def planner_for(path: str, meta: FileMeta, columns: Sequence[str],

@@ -22,11 +22,13 @@ import dataclasses
 import functools
 import os
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace as trace_mod
 from repro.core.fused import (FUSED_KEY, Compare, FusedSpec, Interval,
                               SumProduct)
 from repro.core.overlap import RunReport, run_blocking, run_overlapped
@@ -43,7 +45,41 @@ Q12_ORDERS_COLUMNS = ["o_orderkey", "o_orderpriority"]
 
 
 def _dev(x):
-    return jnp.asarray(np.asarray(x))
+    """A decoded column on the device, by way of the host.  With the
+    recorder on, the wait for a column not yet ready, the copy to the host
+    and the copy back are three spans (``device_wait``, ``to_host``,
+    ``to_device``)."""
+    tr = trace_mod.active()
+    if tr is None:
+        return jnp.asarray(np.asarray(x))
+    if isinstance(x, jax.Array) and not x.is_ready():
+        # a wait of its own, only where there is one: each extra block
+        # hands the interpreter lock back and forth once more
+        t0 = time.perf_counter()
+        x.block_until_ready()
+        tr.complete("device_wait", "device", t0, time.perf_counter(),
+                    site="_dev")
+    t0 = time.perf_counter()
+    host = np.asarray(x)
+    t1 = time.perf_counter()
+    out = jnp.asarray(host)
+    t2 = time.perf_counter()
+    tr.complete("to_host", "consume", t0, t1, bytes=host.nbytes)
+    tr.complete("to_device", "consume", t1, t2, bytes=host.nbytes)
+    return out
+
+
+def _to_host(convert, x, site: str):
+    """``convert(x)`` for a small device result; with the recorder on, the
+    call is a ``device_wait`` span naming the site, as its copy is a few
+    bytes and the rest is the wait."""
+    tr = trace_mod.active()
+    if tr is None:
+        return convert(x)
+    t0 = time.perf_counter()
+    out = convert(x)
+    tr.complete("device_wait", "device", t0, time.perf_counter(), site=site)
+    return out
 
 
 def _is_dataset(source) -> bool:
@@ -144,7 +180,7 @@ def _q6_consume(use_kernel: bool):
                                  dlo=0.05, dhi=0.07, qmax=24.0)
         else:
             part = _q6_jnp(ship, disc, qty, price)
-        part = float(part)
+        part = _to_host(float, part, "q6_partial")
         return part if acc is None else acc + part
 
     return consume
@@ -346,7 +382,6 @@ def q12(lineitem_scanner: Scanner, orders_scanner: Scanner,
     dataset sides — the probe side's fingerprint carries the orders
     side's content identity, so a build-table change invalidates it."""
     if trace:
-        from repro.core import trace as trace_mod
         with trace_mod.request(trace):
             return q12(lineitem_scanner, orders_scanner,
                        overlapped=overlapped, prepare_plan=prepare_plan,
@@ -468,7 +503,7 @@ def q12(lineitem_scanner: Scanner, orders_scanner: Scanner,
                 result_cache=result_cache, fingerprint=lfp)
     else:
         counts, probe_report = runner(lineitem_scanner, probe_consume)
-    counts = np.asarray(counts)
+    counts = _to_host(np.asarray, counts, "q12_counts")
     result = {
         "MAIL_high": int(counts[0]), "MAIL_low": int(counts[1]),
         "SHIP_high": int(counts[2]), "SHIP_low": int(counts[3]),

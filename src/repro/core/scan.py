@@ -181,9 +181,14 @@ class _PlannedDecodeJob(DecodeJob):
 
     def finalize(self):
         out = self.planner.finish_execute(self.ctx)
+        tr = trace.active()
+        t0 = time.perf_counter() if tr is not None else 0.0
         for res in out.values():
             if res.on_device:
                 res.array.block_until_ready()
+        if tr is not None:
+            tr.complete("device_wait", "device", t0, time.perf_counter(),
+                        site="finalize")
         return out
 
 

@@ -627,8 +627,13 @@ class ScanService:
                     f"tenant {ten.name}: {ten.active} active scans at "
                     f"max_active={ten.max_active}")
             reg.counter_inc("scheduler.admission_queued")
+            tr = trace.active()
+            t0 = time.perf_counter() if tr is not None else 0.0
             while ten.active >= ten.max_active and not self._shutdown:
                 self._admit_cv.wait(timeout=0.1)
+            if tr is not None:
+                tr.complete("queued", "frontend", t0, time.perf_counter(),
+                            tenant=ten.name)
             if self._shutdown:
                 raise RuntimeError("ScanService is shut down")
         if ten.active == 0:
@@ -888,7 +893,6 @@ class ScanService:
         while self._window_nbytes > self.window_bytes and self._window:
             _, evicted = self._window.popitem(last=False)
             self._window_nbytes -= evicted[5]
-            trace.registry().counter_inc("scheduler.window_evictions")
 
     def _fetch_loop(self) -> None:
         while True:
@@ -1162,9 +1166,6 @@ class ScanService:
     def _ack_locked(self, scan: _ScanState, item: tuple | None,
                     consume_dt: float) -> None:
         scan.credits += 1
-        if trace.active() is not None:
-            trace.registry().observe("scheduler.credits_on_ack",
-                                     scan.credits)
         scan.workers_seen = max(scan.workers_seen, self.pool_size)
         if item is not None:
             # consume is per-consumer; fetch accrued at fetch time and

@@ -498,8 +498,6 @@ class PrefetchingStorage:
             tr = trace.active()
             if tr is not None:
                 tr.instant("prefetch_issue", "io", n=accepted)
-            trace.registry().counter_inc("storage.prefetch_issued",
-                                         accepted)
         return accepted
 
     # -- consume ------------------------------------------------------------

@@ -31,11 +31,21 @@ record; any other non-empty non-zero value → record *and* export to that
 path at process exit), or programmatically via ``trace.request(...)`` —
 the refcounted context manager behind every ``trace=`` kwarg
 (``run_overlapped``, ``run_dataset_scan``, …): ``True`` records for the
-duration, a path string additionally exports on exit.
+duration, a path string additionally exports on exit.  The query front
+end also follows the JAX profiler (``follow_profiler``): a query served
+while a profiler session collects host events turns the recorder on,
+with a cap sized for a whole measured window (``REPRO_TRACE_CAP`` still
+overrides it); ``followed`` hands that recorder over once.
+
+One clock: every recorder, once created, and every query the front end
+starts emit a clock anchor (``clock_anchor``), a ``repro_clock`` profiler
+annotation that carries its own ``perf_counter_ns``.  From the anchors a
+profile records, ``bench/span_reduce.py`` maps the recorder's events
+onto the device trace's time base.
 
 The **metrics registry** is the aggregate sibling: process-wide
-counters / gauges / histograms (pool depth, queue wait, inflight
-credits, steals, kernel launches) that cost one dict update at coarse
+counters / gauges / histograms (pool depth, queue wait, fetch wall,
+steals, retries) that cost one dict update at coarse
 boundaries and snapshot into ``ScanMetrics.registry_snapshot`` /
 ``DatasetRunReport.registry_snapshot`` — informational columns only,
 never a gated count.  Registry updates at per-item granularity are also
@@ -55,6 +65,17 @@ Event vocabulary (``tools/trace_report.py`` buckets on these):
                   quarantine
   cat "fault"     fault_injected, requeue, checksum_failure, deadline
   cat "kernel"    kernel_launch (instant, counted n)
+  cat "frontend"  queued (submit to run, and any admission wait)
+  cat "device"    device_wait (the host blocked on a device result;
+                  args.site names where)
+
+The decode plan's pallas groups add ``pack`` (arena and array fill on
+the host) and ``stage`` (their host→device transfer) under cat
+"decode"; the unfused consume's round trip adds ``to_host`` and
+``to_device`` under cat "consume".  With the recorder on, a column not
+yet ready is waited for first (a ``device_wait``), so each transfer span
+holds the transfer alone; a small result brought to the host (Q6's
+partial sums, Q12's counts) is a ``device_wait`` as a whole.
 
 Multi-tenant attribution (DESIGN.md §11): fetch and decode-item spans
 emitted by the scheduler carry an ``args.tenant`` tag when the scan was
@@ -85,6 +106,27 @@ DEFAULT_CAP = 65_536
 #: per-scan share of the buffer: one scan label may hold at most this
 #: fraction of the global cap before its events start dropping
 PER_SCAN_FRACTION = 0.5
+#: event cap of a recorder that a profiler session turned on (REPRO_TRACE_CAP
+#: overrides): a 51 s window of Q6 over 486 one-page row groups records
+#: about 200k events
+PROFILER_CAP = 1 << 20
+#: name of the clock-anchor annotation (``clock_anchor``)
+ANCHOR = "repro_clock"
+
+
+def clock_anchor() -> None:
+    """A profiler annotation named ``ANCHOR`` that carries the
+    ``perf_counter_ns`` taken as it opens: one point on both clocks.
+    Outside a profiler session it is an inactive TraceMe."""
+    from jax.profiler import TraceAnnotation
+    with TraceAnnotation(ANCHOR, pc_ns=time.perf_counter_ns()):
+        pass
+
+
+def _profiling() -> bool:
+    """True while a JAX profiler session collects host events."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation.is_enabled()
 
 
 class TraceEvent:
@@ -183,6 +225,7 @@ class Tracer:
         self._per_scan: dict[object, int] = {}
         self.dropped = 0
         self.dropped_by_scan: dict[object, int] = {}
+        clock_anchor()
 
     # -- recording ----------------------------------------------------------
 
@@ -295,6 +338,7 @@ _tracer: Tracer | None = None
 _env_checked = False
 _requests = 0          # active trace.request() contexts
 _env_on = False        # REPRO_TRACE kept the tracer on
+_followed: Tracer | None = None   # the recorder follow_profiler turned on
 _registry = MetricsRegistry()
 
 
@@ -357,16 +401,63 @@ def disable() -> None:
         _tracer = None
 
 
+def follow_profiler() -> Tracer | None:
+    """The live tracer for one served query (the front end's per-query
+    check).  While a JAX profiler session collects host events the
+    recorder is on: turned on here, with ``REPRO_TRACE_CAP`` or else
+    ``PROFILER_CAP``, if nothing else turned it on.  A recorder this
+    turned on goes off at ``stop_following``, and is let go at the first
+    call after the session has ended if ``followed`` has not taken it."""
+    global _tracer, _followed
+    tr = active()
+    if tr is None:
+        if not _profiling():
+            if _followed is not None:
+                followed()
+            return None
+        cap = int(os.environ.get("REPRO_TRACE_CAP", PROFILER_CAP))
+        with _lock:
+            if _tracer is None:
+                _tracer = _followed = Tracer(cap=cap)
+            return _tracer
+    if tr is _followed and not _profiling():
+        followed()
+        return None
+    return tr
+
+
+def stop_following() -> None:
+    """Turn off a recorder that ``follow_profiler`` turned on; its events
+    stay for ``followed``."""
+    global _tracer
+    with _lock:
+        if _tracer is not None and _tracer is _followed:
+            _tracer = None
+
+
+def followed() -> Tracer | None:
+    """Hand over the recorder the newest profiler session turned on, once:
+    it goes off if it is still on, and this module keeps no reference to
+    it or its events."""
+    global _tracer, _followed
+    with _lock:
+        tr, _followed = _followed, None
+        if tr is not None and _tracer is tr:
+            _tracer = None
+    return tr
+
+
 def reset() -> None:
     """Test hook: drop the tracer, forget the env resolution, zero the
     refcount, and clear the registry — the next ``active()`` re-reads
     REPRO_TRACE."""
-    global _tracer, _env_checked, _requests, _env_on
+    global _tracer, _env_checked, _requests, _env_on, _followed
     with _lock:
         _tracer = None
         _env_checked = False
         _requests = 0
         _env_on = False
+        _followed = None
     _registry.clear()
 
 
@@ -393,7 +484,7 @@ class _Request:
         with _lock:
             _requests = max(0, _requests - 1)
             last = _requests == 0
-        if last and not _env_on:
+        if last and not _env_on and self.tracer is not _followed:
             disable()
 
 

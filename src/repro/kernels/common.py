@@ -57,7 +57,6 @@ def count_launch(n: int = 1) -> None:
     tr = trace.active()
     if tr is not None:
         tr.instant("kernel_launch", "kernel", n=n)
-        trace.registry().counter_inc("kernels.launches", n)
 
 
 def kernel_launch_count() -> int:

@@ -146,6 +146,10 @@ class CachedDictionary:
         return self._device
 
     @property
+    def on_device(self) -> bool:
+        return self._device is not None
+
+    @property
     def nbytes(self) -> int:
         return int(self.host.nbytes)
 

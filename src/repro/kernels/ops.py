@@ -102,43 +102,39 @@ def _stats_fit_int32(chunk: ChunkMeta) -> bool:
 #
 # These accept already-batched (n_pages, …) arrays so a caller may batch
 # pages from *many* column chunks into one pallas_call (the DecodePlan path,
-# core/decode_plan.py).  The per-chunk decoders below are thin assemblers
-# over these and remain the reference/fallback path.
+# core/decode_plan.py, which stages them on the device itself; host arrays
+# are transferred by the kernel's jit).  The per-chunk decoders below are
+# thin assemblers over these and remain the reference/fallback path.
 # ---------------------------------------------------------------------------
 
 def decode_dict_group(words: np.ndarray, dictionaries: np.ndarray,
                       width: int) -> jnp.ndarray:
     """words (n_pages, G*width) u32; dictionaries (n_pages, D) — one padded
     dictionary row per page (pages may come from different columns)."""
-    return dict_decode_pages_multi(jnp.asarray(words),
-                                   jnp.asarray(dictionaries), width=width)
+    return dict_decode_pages_multi(words, dictionaries, width=width)
 
 
 def decode_dict_group_shared(words: np.ndarray, dictionary: np.ndarray,
                              width: int) -> jnp.ndarray:
     """Single-column group: one dictionary shared by every page — no
     per-page duplication (same kernel as the per-chunk reference path)."""
-    return dict_decode_pages(jnp.asarray(words), jnp.asarray(dictionary),
-                             width=width)
+    return dict_decode_pages(words, dictionary, width=width)
 
 
 def decode_delta_group(payload: np.ndarray, mb_off: np.ndarray,
                        mb_width: np.ndarray, min_delta: np.ndarray,
                        first: np.ndarray, n_blocks: int) -> jnp.ndarray:
-    return delta_decode_pages(
-        jnp.asarray(payload), jnp.asarray(mb_off), jnp.asarray(mb_width),
-        jnp.asarray(min_delta), jnp.asarray(first), n_blocks=n_blocks)
+    return delta_decode_pages(payload, mb_off, mb_width, min_delta, first,
+                              n_blocks=n_blocks)
 
 
 def decode_rle_group(vals: np.ndarray, counts: np.ndarray,
                      n_out: int) -> jnp.ndarray:
-    return rle_decode_pages(jnp.asarray(vals), jnp.asarray(counts),
-                            n_out=n_out)
+    return rle_decode_pages(vals, counts, n_out=n_out)
 
 
 def decode_bss_group(payload: np.ndarray, stride: int) -> jnp.ndarray:
-    return bss_decode_pages(jnp.asarray(payload), stride_words=stride,
-                            n_out=stride * 4)
+    return bss_decode_pages(payload, stride_words=stride, n_out=stride * 4)
 
 
 def delta_group_arrays(mans: Sequence[dict], payloads: Sequence[bytes],

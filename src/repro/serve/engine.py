@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.models.model import Model
 
 
@@ -140,7 +141,8 @@ class ServeEngine:
 @dataclasses.dataclass
 class QueryTicket:
     """One submitted query's lifecycle record.  ``state`` walks
-    queued → running → done | failed | rejected | cancelled."""
+    queued → running → done | failed | rejected | cancelled; the stamps
+    are ``time.perf_counter()`` seconds, the flight recorder's clock."""
 
     id: str
     tenant: str
@@ -150,6 +152,7 @@ class QueryTicket:
     reports: tuple = ()
     error: BaseException | None = None
     submitted_at: float = 0.0
+    started_at: float = 0.0
     finished_at: float = 0.0
 
     @property
@@ -170,7 +173,12 @@ class QueryFrontEnd:
     rejection or queueing), and an optional ``slo_s`` latency target
     feeding the adaptive pool sizer.  Each submitted query runs on its
     own thread; ``cancel`` is best-effort — a queued ticket never runs,
-    a running ticket's result is discarded at completion."""
+    a running ticket's result is discarded at completion.
+
+    A query served while a JAX profiler session runs turns the flight
+    recorder on (``trace.follow_profiler``), records its ``queued`` span
+    and emits a clock anchor as it starts; ``shutdown`` turns such a
+    recorder off."""
 
     DEFAULT_WINDOW_BYTES = 64 << 20
 
@@ -220,7 +228,7 @@ class QueryFrontEnd:
                 raise RuntimeError("QueryFrontEnd is shut down")
             tid = f"t{next(self._ids)}"
             ticket = QueryTicket(id=tid, tenant=tenant, query=query,
-                                 submitted_at=time.monotonic())
+                                 submitted_at=time.perf_counter())
             self._tickets[tid] = ticket
             t = threading.Thread(
                 target=self._run, args=(ticket, source, query_kwargs),
@@ -236,6 +244,13 @@ class QueryFrontEnd:
             if ticket.state == "cancelled":
                 return
             ticket.state = "running"
+            ticket.started_at = time.perf_counter()
+        tr = trace.follow_profiler()
+        if tr is not None:
+            tr.complete("queued", "frontend", ticket.submitted_at,
+                        ticket.started_at, ticket=ticket.id,
+                        tenant=ticket.tenant)
+            trace.clock_anchor()
         try:
             if ticket.query == "q6":
                 acc, report = q6(source, service=self._service,
@@ -254,21 +269,21 @@ class QueryFrontEnd:
                 if ticket.state != "cancelled":
                     ticket.state = "rejected"
                     ticket.error = e
-                ticket.finished_at = time.monotonic()
+                ticket.finished_at = time.perf_counter()
             return
         except BaseException as e:  # noqa: BLE001 — surfaced via poll
             with self._lock:
                 if ticket.state != "cancelled":
                     ticket.state = "failed"
                     ticket.error = e
-                ticket.finished_at = time.monotonic()
+                ticket.finished_at = time.perf_counter()
             return
         with self._lock:
             if ticket.state != "cancelled":   # cancelled → discard result
                 ticket.result = result
                 ticket.reports = reports
                 ticket.state = "done"
-            ticket.finished_at = time.monotonic()
+            ticket.finished_at = time.perf_counter()
 
     def poll(self, ticket_id: str) -> dict:
         """Non-blocking status: ``state``, ``result`` (when done),
@@ -276,7 +291,7 @@ class QueryFrontEnd:
         with self._lock:
             ticket = self._tickets[ticket_id]
             end = (ticket.finished_at if ticket.finished
-                   else time.monotonic())
+                   else time.perf_counter())
             return {
                 "id": ticket.id, "tenant": ticket.tenant,
                 "query": ticket.query, "state": ticket.state,
@@ -312,7 +327,7 @@ class QueryFrontEnd:
             if ticket.finished:
                 return False
             ticket.state = "cancelled"
-            ticket.finished_at = time.monotonic()
+            ticket.finished_at = time.perf_counter()
             return True
 
     def tickets(self, tenant: str | None = None) -> list[dict]:
@@ -327,6 +342,7 @@ class QueryFrontEnd:
             threads = list(self._threads.values())
         for t in threads:
             t.join(timeout)
+        trace.stop_following()
         if self._own_service:
             self._service.shutdown()
 

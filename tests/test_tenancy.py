@@ -398,6 +398,27 @@ def test_admission_queue_blocks_until_slot_frees():
         svc.shutdown()
 
 
+def test_admission_queue_wait_is_a_queued_span():
+    svc = ScanService(workers=1, adaptive=False)
+    tr = trace.enable()
+    try:
+        svc.register_tenant("q", weight=1, max_active=1, on_limit="queue")
+        h1 = svc.submit(_StubScanner(64), tenant="q", depth=1)
+        t = threading.Thread(
+            target=lambda: list(svc.submit(_StubScanner(4), tenant="q")),
+            daemon=True)
+        t.start()
+        time.sleep(0.3)
+        h1.cancel()
+        t.join(timeout=5.0)
+        (span,) = [e for e in tr.events() if e.name == "queued"]
+        assert span.cat == "frontend" and span.args == {"tenant": "q"}
+        assert span.dur >= 0.25
+    finally:
+        svc.shutdown()
+        trace.reset()
+
+
 def test_admission_unknown_tenant_auto_registers_weight1():
     svc = ScanService(workers=1, adaptive=False)
     try:
@@ -673,6 +694,29 @@ def test_frontend_submit_poll_result_round_trip(small_tpch):
         # delivered-result window served it (strictly fewer io_requests)
         assert reports1[0].metrics.n_io_requests >= 0
         assert fe.service.window_hits > 0 or fe.service.shared_rgs > 0
+
+
+def test_frontend_started_at_stamp_and_spans(small_tpch):
+    tr = trace.enable()
+    try:
+        with QueryFrontEnd(workers=1) as fe:
+            tid = fe.submit("gold", "q6", _q6_scanner(small_tpch),
+                            prune=False)
+            fe.result(tid, timeout=60)
+            ticket = fe._tickets[tid]
+        assert (0 < ticket.submitted_at <= ticket.started_at
+                <= ticket.finished_at)
+        (queued,) = [e for e in tr.events() if e.name == "queued"]
+        assert queued.cat == "frontend"
+        assert queued.args == {"ticket": tid, "tenant": "gold"}
+        # the queue wait runs from submit to the run's start, on the
+        # ticket's stamps
+        assert tr.epoch + queued.ts == pytest.approx(ticket.submitted_at,
+                                                     abs=1e-9)
+        assert tr.epoch + queued.ts + queued.dur == pytest.approx(
+            ticket.started_at, abs=1e-9)
+    finally:
+        trace.reset()
 
 
 def test_frontend_rejected_ticket(small_tpch):

@@ -8,6 +8,7 @@ import json
 import os
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -534,3 +535,159 @@ def test_dataset_scan_trace_kwarg(tmp_path):
     assert trace_report.validate_trace(doc) == []
     names = {e["name"] for e in doc["traceEvents"]}
     assert {"fragment", "dataset_scan"} <= names
+
+
+# -- the served path: front end, staging, round trips, device waits ----------
+
+@pytest.fixture(scope="module")
+def q6_file(tmp_path_factory):
+    from repro.data import tpch
+    d = tmp_path_factory.mktemp("trace_q6")
+    cfg = ACCELERATOR_OPTIMIZED.replace(rows_per_rg=4_000,
+                                        target_pages_per_chunk=4)
+    return tpch.write_tpch(str(d), sf=0.002, config=cfg,
+                           seed=5)["lineitem_path"]
+
+
+def _served_q6(path):
+    """One Q6 through the front end on a pallas scanner, with every
+    ``block_until_ready`` counted by the function that called it."""
+    from jax._src.array import ArrayImpl
+
+    from repro.serve.engine import QueryFrontEnd
+    callers = []
+    real = ArrayImpl.block_until_ready
+
+    def counted(self):
+        callers.append(sys._getframe(1).f_code.co_name)
+        return real(self)
+
+    sc = open_scanner(path, columns=list(Q6_COLUMNS),
+                      decode_backend="pallas")
+    ArrayImpl.block_until_ready = counted
+    try:
+        with QueryFrontEnd(workers=1, window_bytes=0) as fe:
+            acc, _ = fe.result(fe.submit("t0", "q6", sc, prune=False),
+                               timeout=300)
+    finally:
+        ArrayImpl.block_until_ready = real
+    return acc, callers
+
+
+def test_served_q6_off_records_nothing_and_adds_no_device_wait(q6_file):
+    acc_off, callers = _served_q6(q6_file)
+    assert trace.active() is None and trace.followed() is None
+    # decode's flush is the only wait with the recorder off
+    flushes = ("finish_execute", "finalize")
+    assert callers and set(callers) <= set(flushes)
+    n_off = [callers.count(f) for f in flushes]
+
+    tr = trace.enable()
+    acc_on, callers = _served_q6(q6_file)
+    assert acc_on == acc_off
+    assert [callers.count(f) for f in flushes] == n_off
+    # the recorder adds a block only for a consumed column not yet ready
+    assert set(callers) - set(flushes) <= {"_dev"}
+    waits = [e.args["site"] for e in _spans(tr, "device_wait")]
+    assert {"finalize", "q6_partial"} <= set(waits) \
+        <= {"finalize", "q6_partial", "_dev"}
+    assert callers.count("_dev") == waits.count("_dev")
+    names = {e.name for e in tr.events()}
+    assert {"queued", "pack", "stage", "to_host", "to_device"} <= names
+    assert all(e.args["bytes"] > 0 for e in _spans(tr, "pack"))
+    # each consumed column went to the host and back
+    (n_to_host, n_to_device) = (len(_spans(tr, "to_host")),
+                                len(_spans(tr, "to_device")))
+    assert n_to_host == n_to_device == len(_spans(tr, "consume")) * 4
+
+
+def test_dev_round_trip_spans_only_when_on(monkeypatch):
+    import jax.numpy as jnp
+    from jax._src.array import ArrayImpl
+
+    from repro.core.query import _dev
+    x = jnp.arange(1024, dtype=jnp.int32)
+    x.block_until_ready()
+    assert np.array_equal(np.asarray(_dev(x)), np.arange(1024))
+    tr = trace.enable()
+    assert np.array_equal(np.asarray(_dev(x)), np.arange(1024))
+    names = [e.name for e in tr.events() if e.ph == "X"]
+    assert names == ["to_host", "to_device"]     # ready: no wait of its own
+    assert _spans(tr, "to_host")[0].args["bytes"] == 4096
+    tr.clear()
+    monkeypatch.setattr(ArrayImpl, "is_ready", lambda self: False)
+    _dev(x)
+    names = [e.name for e in tr.events() if e.ph == "X"]
+    assert names == ["device_wait", "to_host", "to_device"]
+    # the three spans tile the call in order
+    w, h, d = (_spans(tr, n)[0] for n in names)
+    assert w.args["site"] == "_dev"
+    assert w.ts + w.dur <= h.ts + 1e-9 and h.ts + h.dur <= d.ts + 1e-9
+
+
+def test_front_end_follows_the_profiler(q6_file, tmp_path):
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _served_q6(q6_file)
+    finally:
+        jax.profiler.stop_trace()
+    # the front end's shutdown turned it off; it is handed over once
+    assert trace.active() is None
+    tr = trace.followed()
+    assert tr is not None and tr.cap == trace.PROFILER_CAP
+    assert trace.followed() is None
+    assert {e.name for e in tr.events()} >= {"queued", "stage"}
+    # no profiler session: the next served query records nothing
+    _served_q6(q6_file)
+    assert trace.active() is None and trace.followed() is None
+
+
+def test_followed_recorder_honours_the_cap_and_is_let_go(monkeypatch,
+                                                         tmp_path):
+    import jax
+    monkeypatch.setenv("REPRO_TRACE_CAP", "4096")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        tr = trace.follow_profiler()
+        assert tr is not None and tr.cap == 4096
+        assert trace.follow_profiler() is tr and trace.active() is tr
+    finally:
+        jax.profiler.stop_trace()
+    # the session ended and nothing took the recorder: the next query's
+    # check turns it off and lets it go
+    assert trace.follow_profiler() is None
+    assert trace.active() is None and trace.followed() is None
+    # the same for one a front end's shutdown already turned off
+    jax.profiler.start_trace(str(tmp_path / "again"))
+    try:
+        assert trace.follow_profiler() is not None
+        trace.stop_following()
+    finally:
+        jax.profiler.stop_trace()
+    assert trace.active() is None
+    assert trace.follow_profiler() is None and trace.followed() is None
+
+
+def test_trace_report_buckets_the_new_spans():
+    for name in ("pack", "stage", "device_wait", "to_host", "to_device"):
+        assert name in trace_report.BUCKET_OF
+    assert "queued" not in trace_report.BUCKET_OF   # front-end framing
+
+
+def test_stage_counts_only_the_bytes_it_moves():
+    from repro.core.decode_plan import _stage
+    from repro.kernels.dict_decode import CachedDictionary
+    arena = np.arange(256, dtype=np.uint32).reshape(2, 128)
+    d = CachedDictionary(np.arange(16, dtype=np.float32))
+    out = _stage(None, 0.0, arena, d)        # recorder off: staged alone
+    assert np.array_equal(np.asarray(out[0]), arena)
+    assert out[1] is d.device and d.on_device
+    tr = trace.enable()
+    fresh = CachedDictionary(np.arange(8, dtype=np.int32))
+    _stage(tr, time.perf_counter(), arena, d, fresh)
+    pack, stage = _spans(tr, "pack"), _spans(tr, "stage")
+    assert [p.args["bytes"] for p in pack] == [1024 + 64 + 32]
+    # the cached dictionary already on the device moves nothing
+    assert [s.args["bytes"] for s in stage] == [1024 + 32]
+    assert pack[0].ts + pack[0].dur <= stage[0].ts + 1e-9

@@ -39,13 +39,17 @@ import sys
 VALID_PH = ("X", "i", "M", "B", "E")
 
 #: span name → attribution bucket; structural spans (scan / fragment /
-#: dataset_scan / …) frame the timeline and are deliberately unmapped
+#: dataset_scan / the front end's queued) frame the timeline and are
+#: deliberately unmapped.  ``pack``/``stage`` nest in decode
+#: items, ``to_host``/``to_device`` in consume; a ``device_wait`` counts
+#: as decode, the device work it waits for, unless a consume covers it.
 BUCKET_OF = {
     "fetch": "fetch", "storage_read": "fetch",
     "decompress": "decompress",
     "open": "decode", "transition": "decode", "decode": "decode",
     "fused": "decode", "finalize": "decode", "decode_rg": "decode",
-    "consume": "consume",
+    "pack": "decode", "stage": "decode", "device_wait": "decode",
+    "consume": "consume", "to_host": "consume", "to_device": "consume",
 }
 
 #: attribution priority, latest pipeline stage first (module docstring)
