@@ -44,28 +44,25 @@ Q12_LINEITEM_COLUMNS = ["l_orderkey", "l_shipmode", "l_shipdate",
 Q12_ORDERS_COLUMNS = ["o_orderkey", "o_orderpriority"]
 
 
-def _dev(x):
-    """A decoded column on the device, by way of the host.  With the
-    recorder on, the wait for a column not yet ready, the copy to the host
-    and the copy back are three spans (``device_wait``, ``to_host``,
-    ``to_device``)."""
+def _on_device(x):
+    """A decoded column as the operand of a jitted reduce or probe.  A
+    ``jax.Array`` is used where it is, with no copy and no wait: the call
+    that reads it orders itself after the kernel that made it, and JAX
+    moves an uncommitted array to the device the call runs on.  A host
+    array (numpy backend, host-fallback decode) is uploaded once; with
+    the recorder on, that upload is a ``to_device`` span with ``bytes``.
+    Nothing here writes into or donates the array, so a result-window
+    entry is read in place."""
+    if isinstance(x, jax.Array):
+        return x
     tr = trace_mod.active()
     if tr is None:
-        return jnp.asarray(np.asarray(x))
-    if isinstance(x, jax.Array) and not x.is_ready():
-        # a wait of its own, only where there is one: each extra block
-        # hands the interpreter lock back and forth once more
-        t0 = time.perf_counter()
-        x.block_until_ready()
-        tr.complete("device_wait", "device", t0, time.perf_counter(),
-                    site="_dev")
-    t0 = time.perf_counter()
+        return jnp.asarray(x)
     host = np.asarray(x)
-    t1 = time.perf_counter()
+    t0 = time.perf_counter()
     out = jnp.asarray(host)
-    t2 = time.perf_counter()
-    tr.complete("to_host", "consume", t0, t1, bytes=host.nbytes)
-    tr.complete("to_device", "consume", t1, t2, bytes=host.nbytes)
+    tr.complete("to_device", "consume", t0, time.perf_counter(),
+                bytes=host.nbytes)
     return out
 
 
@@ -162,10 +159,10 @@ def _q6_consume_fused(use_kernel: bool):
 
 def _q6_consume(use_kernel: bool):
     def consume(acc, rg_index, cols):
-        ship = _dev(cols["l_shipdate"].array).astype(jnp.int32)
-        disc = _dev(cols["l_discount"].array).astype(jnp.float32)
-        qty = _dev(cols["l_quantity"].array).astype(jnp.float32)
-        price = _dev(cols["l_extendedprice"].array).astype(jnp.float32)
+        ship = _on_device(cols["l_shipdate"].array).astype(jnp.int32)
+        disc = _on_device(cols["l_discount"].array).astype(jnp.float32)
+        qty = _on_device(cols["l_quantity"].array).astype(jnp.float32)
+        price = _on_device(cols["l_extendedprice"].array).astype(jnp.float32)
         if use_kernel:
             n = ship.shape[0]
             pad = (-n) % TILE
@@ -406,8 +403,8 @@ def q12(lineitem_scanner: Scanner, orders_scanner: Scanner,
         orders_scanner.prepare_plans()
     # Build side: stream orders, then sort once on device.
     def build_consume(acc, rg_index, cols):
-        k = _dev(cols["o_orderkey"].array).astype(jnp.int32)
-        p = _dev(cols["o_orderpriority"].array).astype(jnp.int32)
+        k = _on_device(cols["o_orderkey"].array).astype(jnp.int32)
+        p = _on_device(cols["o_orderpriority"].array).astype(jnp.int32)
         return (k, p) if acc is None else (jnp.concatenate([acc[0], k]),
                                            jnp.concatenate([acc[1], p]))
 
@@ -467,11 +464,11 @@ def q12(lineitem_scanner: Scanner, orders_scanner: Scanner,
             return part if acc is None else acc + part
         part = _q12_probe(
             skeys, sprio,
-            _dev(cols["l_orderkey"].array).astype(jnp.int32),
-            _dev(cols["l_shipmode"].array).astype(jnp.int32),
-            _dev(cols["l_shipdate"].array).astype(jnp.int32),
-            _dev(cols["l_commitdate"].array).astype(jnp.int32),
-            _dev(cols["l_receiptdate"].array).astype(jnp.int32))
+            _on_device(cols["l_orderkey"].array).astype(jnp.int32),
+            _on_device(cols["l_shipmode"].array).astype(jnp.int32),
+            _on_device(cols["l_shipdate"].array).astype(jnp.int32),
+            _on_device(cols["l_commitdate"].array).astype(jnp.int32),
+            _on_device(cols["l_receiptdate"].array).astype(jnp.int32))
         return part if acc is None else acc + part
 
     if _is_dataset(lineitem_scanner):

@@ -71,11 +71,10 @@ Event vocabulary (``tools/trace_report.py`` buckets on these):
 
 The decode plan's pallas groups add ``pack`` (arena and array fill on
 the host) and ``stage`` (their host→device transfer) under cat
-"decode"; the unfused consume's round trip adds ``to_host`` and
-``to_device`` under cat "consume".  With the recorder on, a column not
-yet ready is waited for first (a ``device_wait``), so each transfer span
-holds the transfer alone; a small result brought to the host (Q6's
-partial sums, Q12's counts) is a ``device_wait`` as a whole.
+"decode"; the unfused consume adds ``to_device`` under cat "consume"
+for a host-resident column it uploads (a device column is read where it
+is).  A small result brought to the host (Q6's partial sums, Q12's
+counts) is a ``device_wait`` as a whole.
 
 Multi-tenant attribution (DESIGN.md §11): fetch and decode-item spans
 emitted by the scheduler carry an ``args.tenant`` tag when the scan was

@@ -130,6 +130,42 @@ def test_q12_against_reference(tpch_files):
     assert got == ref
 
 
+def test_served_unfused_pallas_repeats_bit_for_bit(tpch_files):
+    """Unfused Q6 and Q12 served twice through a front end with a result
+    window, on pallas scanners: every answer matches the numpy reference,
+    and the repeats, whose consume reads window entries in place, match
+    the first answers bit for bit."""
+    from repro.serve.engine import QueryFrontEnd
+    metas, line, orders = tpch_files
+    ref6 = q6_reference({c: np.asarray(line[c]) for c in Q6_COLUMNS})
+    ref12 = q12_reference(
+        {c: np.asarray(line[c]) for c in Q12_LINEITEM_COLUMNS},
+        {c: np.asarray(orders[c]) for c in Q12_ORDERS_COLUMNS})
+
+    def scanner(path, columns):
+        return open_scanner(path, columns=list(columns),
+                            decode_backend="pallas")
+
+    q6_src = scanner(metas["lineitem_path"], Q6_COLUMNS)
+    q12_src = (scanner(metas["lineitem_path"], Q12_LINEITEM_COLUMNS),
+               scanner(metas["orders_path"], Q12_ORDERS_COLUMNS))
+    assert len(q6_src.meta.row_groups) > 1
+    answers = []
+    with QueryFrontEnd(workers=2, window_bytes=64 << 20) as fe:
+        for _ in range(2):
+            a6, _ = fe.result(fe.submit("t0", "q6", q6_src, prune=False,
+                                        fused=False, decode_workers=2),
+                              timeout=300)
+            a12, _ = fe.result(fe.submit("t0", "q12", q12_src, fused=False,
+                                         decode_workers=2), timeout=300)
+            answers.append((a6, a12))
+        assert fe.service.window_hits > 0
+    for a6, a12 in answers:
+        assert abs(a6 - ref6) / abs(ref6) <= 1e-5
+        assert a12 == ref12
+    assert answers[1] == answers[0]
+
+
 def test_cascade_file_scans(tmp_path, tpch_files):
     _, line, _ = tpch_files
     from repro.core import write_table

@@ -585,44 +585,55 @@ def test_served_q6_off_records_nothing_and_adds_no_device_wait(q6_file):
     tr = trace.enable()
     acc_on, callers = _served_q6(q6_file)
     assert acc_on == acc_off
+    # the recorder adds no block of its own
     assert [callers.count(f) for f in flushes] == n_off
-    # the recorder adds a block only for a consumed column not yet ready
-    assert set(callers) - set(flushes) <= {"_dev"}
+    assert set(callers) <= set(flushes)
     waits = [e.args["site"] for e in _spans(tr, "device_wait")]
-    assert {"finalize", "q6_partial"} <= set(waits) \
-        <= {"finalize", "q6_partial", "_dev"}
-    assert callers.count("_dev") == waits.count("_dev")
+    assert set(waits) == {"finalize", "q6_partial"}
     names = {e.name for e in tr.events()}
-    assert {"queued", "pack", "stage", "to_host", "to_device"} <= names
+    assert {"queued", "pack", "stage", "consume"} <= names
     assert all(e.args["bytes"] > 0 for e in _spans(tr, "pack"))
-    # each consumed column went to the host and back
-    (n_to_host, n_to_device) = (len(_spans(tr, "to_host")),
-                                len(_spans(tr, "to_device")))
-    assert n_to_host == n_to_device == len(_spans(tr, "consume")) * 4
+    # the consume reads the decoded columns where they are: no copy to
+    # the host and back, and no upload
+    assert not _spans(tr, "to_host") and not _spans(tr, "to_device")
 
 
-def test_dev_round_trip_spans_only_when_on(monkeypatch):
+@pytest.mark.parametrize("kind", ["ready", "not_ready", "host"])
+def test_on_device_moves_only_host_columns(kind, monkeypatch):
+    import jax
     import jax.numpy as jnp
     from jax._src.array import ArrayImpl
 
-    from repro.core.query import _dev
-    x = jnp.arange(1024, dtype=jnp.int32)
-    x.block_until_ready()
-    assert np.array_equal(np.asarray(_dev(x)), np.arange(1024))
+    from repro.core.query import _on_device
+    want = np.arange(1024, dtype=np.int32)
+    if kind == "host":
+        x = want.copy()
+    else:
+        x = jnp.asarray(want)
+        x.block_until_ready()
+
+        def no_wait(self):
+            raise AssertionError("a device column was waited for")
+        monkeypatch.setattr(ArrayImpl, "block_until_ready", no_wait)
+        if kind == "not_ready":
+            monkeypatch.setattr(ArrayImpl, "is_ready", lambda self: False)
+    assert trace.active() is None
+    off = _on_device(x)
     tr = trace.enable()
-    assert np.array_equal(np.asarray(_dev(x)), np.arange(1024))
-    names = [e.name for e in tr.events() if e.ph == "X"]
-    assert names == ["to_host", "to_device"]     # ready: no wait of its own
-    assert _spans(tr, "to_host")[0].args["bytes"] == 4096
-    tr.clear()
-    monkeypatch.setattr(ArrayImpl, "is_ready", lambda self: False)
-    _dev(x)
-    names = [e.name for e in tr.events() if e.ph == "X"]
-    assert names == ["device_wait", "to_host", "to_device"]
-    # the three spans tile the call in order
-    w, h, d = (_spans(tr, n)[0] for n in names)
-    assert w.args["site"] == "_dev"
-    assert w.ts + w.dur <= h.ts + 1e-9 and h.ts + h.dur <= d.ts + 1e-9
+    on = _on_device(x)
+    for out in (off, on):
+        assert isinstance(out, jax.Array)
+        assert np.array_equal(np.asarray(out), want)
+    spans = [e for e in tr.events() if e.ph == "X"]
+    if kind == "host":
+        # uploaded once per call, and only the call with the recorder on
+        # records it
+        assert [e.name for e in spans] == ["to_device"]
+        assert spans[0].args["bytes"] == x.nbytes == 4096
+    else:
+        # a device column comes back in place: no copy, no span, no wait
+        assert off is x and on is x
+        assert spans == []
 
 
 def test_front_end_follows_the_profiler(q6_file, tmp_path):

@@ -41,7 +41,8 @@ VALID_PH = ("X", "i", "M", "B", "E")
 #: span name → attribution bucket; structural spans (scan / fragment /
 #: dataset_scan / the front end's queued) frame the timeline and are
 #: deliberately unmapped.  ``pack``/``stage`` nest in decode
-#: items, ``to_host``/``to_device`` in consume; a ``device_wait`` counts
+#: items, ``to_device`` (a host-resident column's upload) in consume,
+#: and ``to_host`` with it for older traces; a ``device_wait`` counts
 #: as decode, the device work it waits for, unless a consume covers it.
 BUCKET_OF = {
     "fetch": "fetch", "storage_read": "fetch",
