@@ -4,7 +4,8 @@
     python bench/control.py --workload <cell> --seeds 1 2 3
 
 For each seed it generates the columns the cell's runs serve (the same
-capped stream that a run writes, ``bench/tpch_gen.py``) and prints, per
+capped stream that a run writes, ``bench/tpch_gen.py``, in the forms the
+configuration's ``widths`` states, ``bench/forms.py``) and prints, per
 query of the cell's mix, the compared number of the control
 (``queries.control``: the reference in the precision below the
 configuration's) against the reference, beside the cell's limit.  A limit has to lie below every control reading.  The
@@ -24,20 +25,22 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from bench import queries, tpch_gen  # noqa: E402
+from bench import forms, queries, tpch_gen  # noqa: E402
 from bench.run import load_cell  # noqa: E402
 
 
 def tables(config: dict, seed: int, needed: dict[str, list[str]]) -> dict:
     """The columns a run of this configuration and seed serves: the same
-    capped stream that ``run.write_tables`` writes."""
+    capped stream that ``run.write_tables`` writes, in the same forms."""
     kept = {t: {c: [] for c in cols} for t, cols in needed.items()}
     for chunk in tpch_gen.capped_chunks(
             config["scale_factor"], seed,
             {t: config["rows"][t] for t in needed}):
         for t, cols in needed.items():
+            stored = forms.convert({c: chunk[t][c] for c in cols},
+                                   config["widths"])
             for c in cols:
-                kept[t][c].append(chunk[t][c])
+                kept[t][c].append(stored[c])
     return {t: {c: np.concatenate(v) for c, v in cols.items()}
             for t, cols in kept.items()}
 
@@ -54,7 +57,7 @@ def main(argv=None) -> int:
         t = tables(cell.config, seed, needed)
         for q in mix:
             want = queries.reference(q, t)
-            name = queries.CHECK[q]
+            name = queries.CHECK[cell.config["widths"]][q]
             print(json.dumps({
                 "workload": args.workload, "seed": seed, "check": name,
                 "control": queries.error(q, queries.control(q, t), want),

@@ -3,8 +3,11 @@
 The footer is JSON followed by its CRC32 (4 bytes), its length (8 bytes,
 little-endian) and the 8-byte magic.  ``logical_bytes`` is what the
 paper's effective bandwidth counts: rows times physical width of the
-columns a query reads.  ``required_stored_bytes`` is a lower bound on
-what any implementation has to bring to the device for a query: the
+columns a query reads, and for a BYTE_ARRAY column its values' lengths
+plus Parquet PLAIN's 4-byte length prefix each, which the footer does not
+hold: the caller counts them from the values it wrote
+(``forms.byte_array_bytes``).  ``required_stored_bytes`` is a lower
+bound on what any implementation has to bring to the device for a query: the
 stored bytes of every page that the footer's page zone maps cannot
 exclude under the query's predicate, with each needed dictionary page.
 """
@@ -15,8 +18,10 @@ import json
 import struct
 
 MAGIC = b"TABF0001"
-# Parquet physical type ids (BOOLEAN, INT32, INT64, FLOAT, DOUBLE)
+# Parquet physical type ids (BOOLEAN, INT32, INT64, FLOAT, DOUBLE); a
+# BYTE_ARRAY (6) has no width
 WIDTH = {0: 1, 1: 4, 2: 8, 4: 4, 5: 8}
+BYTE_ARRAY = 6
 
 
 def read(path: str) -> dict:
@@ -30,9 +35,13 @@ def read(path: str) -> dict:
         return json.loads(f.read(n)[:-4])
 
 
-def logical_bytes(meta: dict, columns: list[str]) -> int:
-    width = {f["name"]: WIDTH[f["physical"]] for f in meta["schema"]}
-    return sum(meta["num_rows"] * width[c] for c in columns)
+def logical_bytes(meta: dict, columns: list[str],
+                  byte_arrays: dict[str, int] | None = None) -> int:
+    """``byte_arrays`` gives each BYTE_ARRAY column's count; a string
+    column it leaves out raises ``KeyError``."""
+    phys = {f["name"]: f["physical"] for f in meta["schema"]}
+    return sum((byte_arrays or {})[c] if phys[c] == BYTE_ARRAY
+               else meta["num_rows"] * WIDTH[phys[c]] for c in columns)
 
 
 def _excluded(pages: dict, zones: dict, i: int) -> bool:

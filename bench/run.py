@@ -11,7 +11,8 @@ by ``bench/metrics/<metric>.py``; a cell's correctness limits are in
 
 A run generates the TPC-H tables from ``--seed`` (``bench/tpch_gen.py``)
 and writes them with the program's own ``TabFileWriter`` under the
-configuration's file layout; it serves the traffic through one
+configuration's file layout, each column in the form its ``widths``
+states (``bench/forms.py``); it serves the traffic through one
 ``QueryFrontEnd`` with its default settings, each source a scanner on the
 pallas decode path, and warms every query of the mix up once.  Clients
 then issue queries in a closed loop for ``--seconds`` seconds; the window
@@ -54,7 +55,7 @@ if ROOT not in sys.path:
 # the TPU runtime logs to /tmp/tpu_logs unless told otherwise
 os.environ.setdefault("TPU_LOG_DIR", os.path.join(CACHE, "tpu_logs"))
 
-from bench import footer, queries, tpch_gen, trace_reduce  # noqa: E402
+from bench import footer, forms, queries, tpch_gen, trace_reduce  # noqa: E402
 
 
 LATE_S = 60.0     # how long past the window's close an answer may come
@@ -89,9 +90,12 @@ def load_cell(name: str, trace: bool) -> Cell:
     if name not in cells:
         raise BenchError(f"no workload {name!r} in BENCHMARK.json")
     w = cells[name]
+    config = _json(os.path.join(BENCH, "configs", f"{w['config']}.json"))
+    if config.get("widths") not in forms.WIDTHS:
+        raise BenchError(f"configuration {w['config']!r} states no widths "
+                         f"(one of {forms.WIDTHS})")
     return Cell(
-        name=name, chips=w["chips"],
-        config=_json(os.path.join(BENCH, "configs", f"{w['config']}.json")),
+        name=name, chips=w["chips"], config=config,
         traffic=_json(os.path.join(BENCH, "traffic",
                                    f"{w['traffic']}.json")),
         limits=_json(os.path.join(BENCH, "limits", f"{name}.json")),
@@ -162,11 +166,13 @@ def write_tables(config: dict, seed: int, workdir: str,
     """Generate the seeded tables chunk by chunk and stream the first
     ``config["rows"][table]`` rows of each table the traffic reads into
     one file, row group by row group, so that every seed gives the same
-    row counts and shapes.  Returns the paths, the footers and the read
-    columns of each table, kept for the reference."""
+    row counts and shapes, each column converted to the form that
+    ``config["widths"]`` states.  Returns the paths, the footers and the
+    read columns of each table in their written form, kept for the
+    reference."""
     import numpy as np
 
-    from repro.core.schema import Field, LogicalType, PhysicalType, Schema
+    from repro.core.schema import Schema
     from repro.core.table import Table
     from repro.core.writer import TabFileWriter
 
@@ -174,8 +180,7 @@ def write_tables(config: dict, seed: int, workdir: str,
     threads = min(16, os.cpu_count() or 1)
     writers, schemas, pending, kept, paths = {}, {}, {}, {}, {}
     for t in needed:
-        schemas[t] = Schema([Field(n, PhysicalType[p], LogicalType(lg))
-                             for n, p, lg in tpch_gen.TABLES[t]])
+        schemas[t] = Schema(forms.fields(t, config["widths"]))
         paths[t] = os.path.join(workdir, f"{t}.tab")
         writers[t] = TabFileWriter(paths[t], fc, threads).begin(schemas[t])
         pending[t], kept[t] = [], {c: [] for c in needed[t]}
@@ -202,10 +207,10 @@ def write_tables(config: dict, seed: int, workdir: str,
     try:
         for chunk in stream:
             for t in needed:
-                cols = chunk[t]
+                cols = forms.convert(chunk[t], config["widths"])
                 if not len(cols[needed[t][0]]):
                     continue
-                pending[t].append(Table(cols, schemas[t]))
+                pending[t].append(Table(forms.writable(cols), schemas[t]))
                 for c in needed[t]:
                     kept[t][c].append(cols[c])
                 flush(t, final=False)
@@ -389,11 +394,14 @@ def measure(args, cell: Cell, require) -> dict:
             log(f"data: {t} {m['num_rows']} rows, {len(m['row_groups'])} "
                 f"row groups, {stored} stored bytes")
         log(f"data written in {time.perf_counter() - t0:.3f} s")
-        logical = {q: sum(footer.logical_bytes(metas[t], cols)
+        widths = cell.config["widths"]
+        text = {t: forms.byte_array_bytes(kept[t]) for t in kept}
+        logical = {q: sum(footer.logical_bytes(metas[t], cols, text[t])
                           for t, cols in queries.COLUMNS[q].items())
                    for q in mix}
+        zones = queries.ZONES[widths]
         required = {q: sum(footer.required_stored_bytes(
-                        metas[t], cols, queries.ZONES[q].get(t, {}))
+                        metas[t], cols, zones[q].get(t, {}))
                         for t, cols in queries.COLUMNS[q].items())
                     for q in mix}
         log(f"bytes per query: logical {logical}, required stored "
@@ -458,7 +466,7 @@ def measure(args, cell: Cell, require) -> dict:
         want = queries.reference(q, {t: kept[t] for t in queries.COLUMNS[q]})
         errs = [queries.error(q, r.result, want) for r in records
                 if r.query == q and not r.error]
-        name = queries.CHECK[q]
+        name = queries.CHECK[widths][q]
         checks[name] = {"value": max(errs) if errs else float("inf"),
                         "limit": cell.limits[name]}
     log(f"reference checked {len(records) - failed} answers in "

@@ -12,15 +12,22 @@ import numpy as np
 import pytest
 from bench.tests.helpers import ROOT
 
-from bench import queries, tpch_gen
+from bench import forms, queries, tpch_gen
 
 SPEC = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 
 
-def tables(sf, seed):
-    chunks = list(tpch_gen.tpch_chunks(sf, seed))
+def tables(sf, seed, widths):
+    chunks = [{t: forms.convert(ch[t], widths) for t in ch}
+              for ch in tpch_gen.tpch_chunks(sf, seed)]
     return {t: {c: np.concatenate([ch[t][c] for ch in chunks])
                 for c, _, _ in tpch_gen.TABLES[t]} for t in tpch_gen.TABLES}
+
+
+def widths_of(w):
+    cfg = next(c for c in SPEC["configs"] if c["name"] == w["config"])
+    with open(os.path.join(ROOT, cfg["file"])) as f:
+        return json.load(f)["widths"]
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
@@ -32,10 +39,11 @@ def test_control_fails_and_reference_passes(cell, seed):
     with open(os.path.join(ROOT, "bench", "traffic",
                            f"{w['traffic']}.json")) as f:
         mix = {c["query"] for c in json.load(f)["clients"]}
-    t = tables(0.01, seed)
+    widths = widths_of(w)
+    t = tables(0.01, seed, widths)
     for q in mix:
         want = queries.reference(q, t)
-        limit = limits[queries.CHECK[q]]
+        limit = limits[queries.CHECK[widths][q]]
         assert queries.error(q, want, want) <= limit
         assert queries.error(q, queries.control(q, t), want) > limit
 

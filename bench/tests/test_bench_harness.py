@@ -28,7 +28,8 @@ def test_cell_resolves_its_files_by_name(cell):
             assert callable(run.load_reader(m["name"]))
         from bench import queries
         for client in c.traffic["clients"]:
-            assert queries.CHECK[client["query"]] in c.limits
+            check = queries.CHECK[c.config["widths"]][client["query"]]
+            assert check in c.limits
     w = next(w for w in SPEC["workloads"] if w["name"] == cell)
     cfg = next(c for c in SPEC["configs"] if c["name"] == w["config"])
     assert os.path.isfile(os.path.join(ROOT, cfg["file"]))

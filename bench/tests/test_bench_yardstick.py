@@ -79,7 +79,8 @@ def test_written_files_match_the_programs_writer(tmp_path):
               for t in ("lineitem", "orders")}
     rows = {t: metas[t].num_rows for t in ("lineitem", "orders")}
     paths, ours, _ = run.write_tables(
-        {"scale_factor": 0.002, "file_layout": layout, "rows": rows}, 5,
+        {"scale_factor": 0.002, "widths": "program", "file_layout": layout,
+         "rows": rows}, 5,
         str(tmp_path / "bench"), needed)
     for t in ("lineitem", "orders"):
         with open(metas[f"{t}_path"], "rb") as a, open(paths[t], "rb") as b:
@@ -106,8 +107,8 @@ def test_zone_maps_exclude_only_pages_no_row_can_pass(tmp_path):
         target_pages_per_chunk=2))
     meta = footer.read(path)
     every = footer.required_stored_bytes(meta, list(cols), {})
-    need = footer.required_stored_bytes(meta, list(cols),
-                                        queries.ZONES["q6"]["lineitem"])
+    need = footer.required_stored_bytes(
+        meta, list(cols), queries.ZONES["program"]["q6"]["lineitem"])
     pages = meta["row_groups"][0]["columns"]
     second = sum(c["pages"][1]["stored_size"]
                  + (c["dict_page"] or {}).get("stored_size", 0)
@@ -117,18 +118,16 @@ def test_zone_maps_exclude_only_pages_no_row_can_pass(tmp_path):
 
 def test_widths_script_writes_tpch_forms_of_the_same_rows(monkeypatch,
                                                           capsys, tmp_path):
-    """``bench/widths.py`` names the codes as the program's generator
-    does, and its exact decimal reference agrees with the float32 form's
-    (the witness for the stand-in cells)."""
+    """``bench/widths.py`` writes a configuration's rows at TPC-H's
+    widths through ``run.write_tables``, and the float32 form of the same
+    rows (the witness for the stand-in cells) agrees with the exact
+    reference over them: Q6 to 1e-6 of the revenue, Q12 exactly."""
     import functools
     import json
 
     import repro.serve.engine as engine
-    from repro.data import tpch
 
     from bench import run, widths
-    assert widths.SHIPMODES == tpch.SHIPMODES
-    assert widths.PRIORITIES == tpch.PRIORITIES
     real = run._json
 
     def tiny(path):
@@ -146,7 +145,17 @@ def test_widths_script_writes_tpch_forms_of_the_same_rows(monkeypatch,
         engine.QueryFrontEnd, window_bytes=0))
     assert widths.main(["--config", "tpch_sf1_gpu_aware",
                         "--seeds", str(2**31 + 3)]) == 0
-    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    data, *lines = [json.loads(x)
+                    for x in capsys.readouterr().out.splitlines()]
+    assert data["widths"] == "tpch" and data["write_s"] > 0
+    assert data["rows"] == {"lineitem": 11_000, "orders": 3_000}
+    assert set(data["stored_bytes"]["lineitem"]) == {
+        c for c, _, _ in tpch_gen.LINEITEM}
     assert [x["query"] for x in lines] == ["q6", "q12"]
-    for x in lines:
-        assert x["witness_error"] < 1e-6
+    q6, q12 = lines
+    assert q6["check"] == "q6_units_err" and isinstance(q6["reference"], int)
+    assert abs(q6["witness_f32_form"] * 1e4 - q6["reference"]) < \
+        1e-6 * q6["reference"]
+    assert q12["witness_error"] == 0
+    assert q6["control_error"] > 1 and q12["control_error"] > 0
+    assert q6["float64_error"] < 0.5

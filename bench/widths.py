@@ -3,18 +3,20 @@
 the program answers.
 
     python bench/widths.py --config tpch_sf1_gpu_aware --seeds 1 2 3
+    python bench/widths.py --config tpch_sf10_gpu_aware --seeds 1 --queries q6
 
-The cells serve TPC-H rows at the program's own widths: DECIMAL(15,2)
-columns as FLOAT and CHAR columns as INT32 codes.  This script writes the
-same rows (the capped stream a run writes, ``bench/tpch_gen.py``) with
-the columns each query reads as a Parquet writer stores TPC-H:
-DECIMAL(15,2) as INT64 scaled by 100 (logical DECIMAL, scale 2), CHAR(10)
-``l_shipmode`` and CHAR(15) ``o_orderpriority`` as BYTE_ARRAY strings,
-dates as INT32 days, keys as INT64.  It serves Q6 and Q12 over them once
-each through ``QueryFrontEnd`` on pallas scanners and prints, per seed and
-query, one JSON line: the program's answer or its error, the host-fallback
-decodes, the exact reference over the decimals and strings, and, as a
-witness, the float64 reference over the float32 form of the same rows.
+It writes the rows that a run of the configuration writes
+(``run.write_tables``) with the configuration's ``widths`` set to
+``"tpch"`` (``bench/forms.py``: DECIMAL(15,2) as INT64 cents, CHAR and
+VARCHAR as BYTE_ARRAY strings, keys as INT64), only the tables the
+queries read.  Per seed it prints one JSON line for the data: the seconds
+the writing took and the stored bytes of each column.  Then it serves each
+query once through ``QueryFrontEnd`` on pallas scanners and prints one
+JSON line per query: the program's answer or its error, the host-fallback
+decodes, the exact reference over the written columns, the control
+(``queries.control``), Q6 in float64 throughout and, as a witness, the reference over the
+program's float32/int32-code forms of the same rows, each compared with
+the exact reference as a run compares.
 The benchmark's own runs never run this.
 """
 
@@ -25,6 +27,7 @@ import json
 import os
 import shutil
 import sys
+import time
 
 import numpy as np
 
@@ -32,80 +35,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from bench import queries  # noqa: E402
+from bench import footer, queries  # noqa: E402
 from bench.control import tables  # noqa: E402
-
-# TPC-H's ship modes and order priorities, indexed by the generator's codes
-SHIPMODES = ["REG AIR", "AIR", "MAIL", "RAIL", "SHIP", "TRUCK", "FOB"]
-PRIORITIES = ["1-URGENT", "2-HIGH", "3-MEDIUM", "4-NOT SPECIFIED", "5-LOW"]
-DECIMALS = ("l_quantity", "l_extendedprice", "l_discount")
-STRINGS = {"l_shipmode": SHIPMODES, "o_orderpriority": PRIORITIES}
-assert SHIPMODES[queries.MAIL] == "MAIL" and SHIPMODES[queries.SHIP] == "SHIP"
-
-
-def cents(v: np.ndarray) -> np.ndarray:
-    return np.rint(v.astype(np.float64) * 100).astype(np.int64)
-
-
-def strings(codes: np.ndarray, names: list[str]):
-    from repro.core.table import StringColumn
-    raw = [n.encode() for n in names]
-    width = max(map(len, raw))
-    grid = np.zeros((len(raw), width), np.uint8)
-    used = np.zeros((len(raw), width), bool)
-    for i, b in enumerate(raw):
-        grid[i, :len(b)] = np.frombuffer(b, np.uint8)
-        used[i, :len(b)] = True
-    lengths = used.sum(1)[codes]
-    offsets = np.zeros(len(codes) + 1, np.int64)
-    np.cumsum(lengths, out=offsets[1:])
-    return StringColumn(offsets, grid[codes][used[codes]])
-
-
-def tpch_form(name: str, col: np.ndarray):
-    """A column as TPC-H's Parquet form stores it: (field, data)."""
-    from repro.core.schema import Field, LogicalType, PhysicalType
-    if name in DECIMALS:
-        return (Field(name, PhysicalType.INT64, LogicalType.DECIMAL, 2),
-                cents(col))
-    if name in STRINGS:
-        return (Field(name, PhysicalType.BYTE_ARRAY, LogicalType.STRING),
-                strings(col, STRINGS[name]))
-    if name.endswith("date"):
-        return Field(name, PhysicalType.INT32, LogicalType.DATE), col
-    if name.endswith("orderkey"):
-        return Field(name, PhysicalType.INT64), col.astype(np.int64)
-    raise KeyError(name)
-
-
-def write(path: str, fc, cols: dict[str, np.ndarray]) -> None:
-    from repro.core.schema import Schema
-    from repro.core.table import Table
-    from repro.core.writer import TabFileWriter
-    forms = {n: tpch_form(n, c) for n, c in cols.items()}
-    table = Table({n: d for n, (_, d) in forms.items()},
-                  Schema([f for f, _ in forms.values()]))
-    w = TabFileWriter(path, fc, min(16, os.cpu_count() or 1)).begin(
-        table.schema)
-    for start in range(0, table.num_rows, fc.rows_per_rg):
-        w.write_row_group(table.slice(
-            start, min(start + fc.rows_per_rg, table.num_rows)))
-    w.finish()
-
-
-def q6_exact(li: dict[str, np.ndarray]) -> float:
-    """Q6 over the decimals in integer cents: exact."""
-    ship, disc, qty = li["l_shipdate"], cents(li["l_discount"]), \
-        cents(li["l_quantity"])
-    m = ((ship >= queries.D1994) & (ship < queries.D1995)
-         & (disc >= 5) & (disc <= 7) & (qty < 2400))
-    return int(np.sum(cents(li["l_extendedprice"])[m] * disc[m])) / 1e4
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--config", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
+    ap.add_argument("--queries", nargs="+", choices=list(queries.COLUMNS),
+                    default=list(queries.COLUMNS))
     args = ap.parse_args(argv)
     from bench import run
     run.import_program()
@@ -113,44 +52,53 @@ def main(argv=None) -> int:
     from repro.kernels.ops import host_fallback_counts
     from repro.serve.engine import QueryFrontEnd
 
-    config = run._json(os.path.join(run.BENCH, "configs",
-                                    f"{args.config}.json"))
-    fc = run.file_config(config["file_layout"])
-    needed = queries.columns_of({"q6", "q12"})
+    config = dict(run._json(os.path.join(run.BENCH, "configs",
+                                         f"{args.config}.json")),
+                  widths="tpch")
+    needed = queries.columns_of(args.queries)
     workdir = os.path.join(run.CACHE, "widths")
     for seed in args.seeds:
         shutil.rmtree(workdir, ignore_errors=True)
         os.makedirs(workdir)
-        t = tables(config, seed, needed)
-        paths = {n: os.path.join(workdir, f"{n}.tab") for n in t}
-        for n, cols in t.items():
-            write(paths[n], fc, cols)
-        want = {"q6": q6_exact(t["lineitem"]),
-                "q12": queries.reference("q12", t)}
-        witness = {"q6": queries.reference("q6", t),
-                   "q12": queries.reference("q12", t)}
+        t0 = time.perf_counter()
+        paths, metas, kept = run.write_tables(config, seed, workdir, needed)
+        print(json.dumps({
+            "config": args.config, "seed": seed, "widths": "tpch",
+            "write_s": time.perf_counter() - t0,
+            "rows": {t: m["num_rows"] for t, m in metas.items()},
+            "stored_bytes": {t: {f["name"]: footer.required_stored_bytes(
+                                     m, [f["name"]], {}) for f in m["schema"]}
+                             for t, m in metas.items()},
+        }), flush=True)
+        witness = tables(dict(config, widths="program"), seed, needed)
         fe = QueryFrontEnd()
         fe.register_tenant("t0")
         try:
-            for q in ("q6", "q12"):
+            for q in args.queries:
                 src = tuple(open_scanner(paths[n], columns=cols,
                                          decode_backend="pallas")
                             for n, cols in queries.COLUMNS[q].items())
+                want = queries.reference(q, kept)
+                seen = queries.reference(q, witness)
                 before = host_fallback_counts()
                 line = {"config": args.config, "seed": seed, "query": q,
-                        "reference": want[q],
-                        "witness_f32_form": witness[q]}
+                        "check": queries.CHECK["tpch"][q],
+                        "reference": want, "witness_f32_form": seen}
                 try:
                     got, _ = fe.result(fe.submit(
                         "t0", q, src[0] if len(src) == 1 else src), 600)
                     line["program"] = queries.answer(q, got)
-                    line["error"] = queries.error(q, line["program"],
-                                                  want[q])
+                    line["error"] = queries.error(q, line["program"], want)
                 except Exception as e:  # noqa: BLE001 — reported
                     line["program"] = None
                     line["error"] = repr(e)[:300]
                 line["host_fallback"] = dict(host_fallback_counts() - before)
-                line["witness_error"] = queries.error(q, witness[q], want[q])
+                line["control_error"] = queries.error(
+                    q, queries.control(q, kept), want)
+                if q == "q6":
+                    line["float64_error"] = queries.error(
+                        q, queries.q6_float(kept["lineitem"], np.float64), want)
+                line["witness_error"] = queries.error(q, seen, want)
                 print(json.dumps(line), flush=True)
         finally:
             fe.shutdown()
