@@ -1079,6 +1079,8 @@ class DecodePlanner:
             if (Encoding(chunk.encoding) == Encoding.RLE
                     and field.physical == PhysicalType.BOOLEAN):
                 arr = arr.astype(jnp.uint8)
+            if field.physical == PhysicalType.INT64:
+                ops.count_int64_narrowed()
             logical = int(arr.dtype.itemsize) * chunk.n_values
         else:
             arr = ordered[0] if len(ordered) == 1 else np.concatenate(ordered)

@@ -332,19 +332,53 @@ def decode_dlba_page(payload: bytes, n: int, field: Field,
 # RLE_DICTIONARY (chunk-level)
 # ---------------------------------------------------------------------------
 
+#: little-endian masks of the first r bytes of a word, r = 0..8
+_BYTE_MASKS = np.array([(1 << (8 * r)) - 1 for r in range(9)], dtype="<u8")
+
+
+def _string_keys(values: StringColumn) -> list[np.ndarray]:
+    """Keys that tell every distinct string apart: its bytes zero-padded
+    to whole 8-byte words, one uint64 key per word, each gathered from the
+    payload as one unaligned load, and its length (which tells "a" from
+    "a\\0")."""
+    lengths = values.lengths()
+    n_words = max(1, -(-int(lengths.max(initial=0)) // 8))
+    pay = np.concatenate([values.payload, np.zeros(8 * n_words, np.uint8)])
+    # every byte offset of the payload as the start of a little-endian word
+    at = np.ndarray(buffer=pay, dtype="<u8", shape=(pay.shape[0] - 7,),
+                    strides=(1,))
+    starts = values.offsets[:-1]
+    keys = []
+    for w in range(n_words):
+        left = np.clip(lengths - 8 * w, 0, 8)
+        keys.append(at[starts + 8 * w] & _BYTE_MASKS[left])
+    return keys + [lengths]
+
+
+def _unique_strings(values: StringColumn) -> tuple[StringColumn, np.ndarray]:
+    """The distinct strings in order of first occurrence, and each row's
+    code into them, from one stable sort of the rows' keys."""
+    n = len(values)
+    if n == 0:
+        return values, np.zeros(0, np.int64)
+    keys = _string_keys(values)
+    order = np.lexsort(keys)        # stable: a value's first row leads
+    new = np.zeros(n, bool)
+    new[0] = True
+    for k in keys:
+        ks = k[order]
+        new[1:] |= ks[1:] != ks[:-1]
+    firsts = order[new]
+    rank = np.empty(firsts.shape[0], np.int64)
+    rank[np.argsort(firsts)] = np.arange(firsts.shape[0])
+    codes = np.empty(n, np.int64)
+    codes[order] = rank[np.cumsum(new) - 1]
+    return values.take(np.sort(firsts)), codes
+
+
 def _unique_with_codes(values: Values) -> tuple[Values, np.ndarray]:
     if isinstance(values, StringColumn):
-        table: dict[bytes, int] = {}
-        codes = np.empty(len(values), dtype=np.int64)
-        order: list[bytes] = []
-        for i, b in enumerate(values.to_pylist()):
-            code = table.get(b)
-            if code is None:
-                code = len(order)
-                table[b] = code
-                order.append(b)
-            codes[i] = code
-        return StringColumn.from_pylist(order), codes
+        return _unique_strings(values)
     uniq, codes = np.unique(np.ascontiguousarray(values),
                             return_inverse=True)
     return uniq, codes.astype(np.int64)

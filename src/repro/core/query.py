@@ -19,10 +19,14 @@ Dates are int32 days since 1992-01-01 (DATE logical type).
 from __future__ import annotations
 
 import dataclasses
+import decimal
 import functools
+import math
+import operator
 import os
 import threading
 import time
+from fractions import Fraction
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +37,7 @@ from repro.core.fused import (FUSED_KEY, Compare, FusedSpec, Interval,
                               SumProduct)
 from repro.core.overlap import RunReport, run_blocking, run_overlapped
 from repro.core.scan import Scanner
+from repro.core.schema import LogicalType
 from repro.kernels.filter_agg import TILE, filter_agg_q6
 
 D_1994_01_01 = 731
@@ -183,6 +188,130 @@ def _q6_consume(use_kernel: bool):
     return consume
 
 
+# ---------------------------------------------------------------------------
+# Q6 over DECIMAL operands: exact, in scaled integers.  The decode narrows
+# an INT64 chunk whose stats fit to int32 on the device; predicate, where
+# and price × discount stay int32, and the sum is exact past 2**31 by
+# 16-bit limbs summed per block of rows, combined on the host in Python
+# ints.  No float lies between the decoded cents and the answer.
+# ---------------------------------------------------------------------------
+
+#: rows per block sum: 2**14 limbs of 16 bits sum below 2**30
+EXACT_BLOCK = 1 << 14
+_INT32 = np.iinfo(np.int32)
+
+
+@dataclasses.dataclass(frozen=True)
+class DecimalQ6:
+    """Q6's constants at the operands' DECIMAL scales: discount in
+    [``disc_lo``, ``disc_hi``] and quantity below ``qty_max``, all in
+    stored units; the answer is in units of 10**-``scale``."""
+    disc_lo: int
+    disc_hi: int
+    qty_max: int
+    scale: int
+
+    def answer(self, units: int) -> decimal.Decimal:
+        return decimal.Decimal(f"{units}E-{self.scale}")
+
+
+def q6_decimal(schema) -> DecimalQ6 | None:
+    """The exact plan of Q6 when the schema gives its discount, quantity
+    and price as DECIMAL; None when none of them is (the float path)."""
+    fields = [schema.field(c) for c in
+              ("l_discount", "l_quantity", "l_extendedprice")]
+    dec = [f.logical == LogicalType.DECIMAL for f in fields]
+    if not any(dec):
+        return None
+    if not all(dec):
+        raise ValueError("Q6 needs l_discount, l_quantity and "
+                         "l_extendedprice all DECIMAL or none of them")
+    sd, sq, sp = (f.decimal_scale for f in fields)
+    return DecimalQ6(disc_lo=math.ceil(Fraction(5, 100) * 10**sd),
+                     disc_hi=math.floor(Fraction(7, 100) * 10**sd),
+                     qty_max=24 * 10**sq, scale=sd + sp)
+
+
+def _check_products_fit(dec: DecimalQ6, price_stats, where: str) -> None:
+    """A qualifying row's price × discount is at most max|price| ×
+    max(|disc_lo|, |disc_hi|); raise unless the chunk stats prove that
+    below 2**31, so no int32 product overflows (a row the predicate drops
+    may wrap: ``where`` discards it)."""
+    if not price_stats or not isinstance(price_stats.get("max"), int):
+        raise ValueError(f"l_extendedprice has no integer min/max stats in "
+                         f"{where}: an exact Q6 cannot bound its products")
+    bound = (max(abs(price_stats["min"]), abs(price_stats["max"]))
+             * max(abs(dec.disc_lo), abs(dec.disc_hi)))
+    if bound > _INT32.max:
+        raise ValueError(f"l_extendedprice × l_discount reaches {bound} in "
+                         f"{where}, past int32: an exact Q6 would overflow")
+
+
+def _exact_operand(res, name: str):
+    """A decoded DECIMAL/DATE column as an int32 device array: a device
+    column is already int32 (narrowed by the decode); a host int64 column
+    is narrowed here, or refused when a value does not fit."""
+    x = res.array
+    if isinstance(x, jax.Array):
+        return x
+    host = np.asarray(x)
+    if host.dtype != np.int32:
+        if host.size and (host.min() < _INT32.min or host.max() > _INT32.max):
+            raise ValueError(f"{name} holds values past int32: an exact Q6 "
+                             "cannot take them")
+        host = host.astype(np.int32)
+    return _on_device(host)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("disc_lo", "disc_hi", "qty_max"))
+def _q6_exact_blocks(ship, disc, qty, price, *, disc_lo, disc_hi, qty_max):
+    """(2, n_blocks) int32: per block of ``EXACT_BLOCK`` rows, the sums of
+    the low and the high 16 bits of each qualifying price × discount."""
+    mask = ((ship >= D_1994_01_01) & (ship < D_1995_01_01)
+            & (disc >= disc_lo) & (disc <= disc_hi) & (qty < qty_max))
+    prod = jnp.where(mask, price * disc, 0)
+    prod = jnp.pad(prod, (0, (-prod.shape[0]) % EXACT_BLOCK))
+    prod = prod.reshape(-1, EXACT_BLOCK)
+    return jnp.stack([jnp.sum(prod & 0xFFFF, axis=1),
+                      jnp.sum(prod >> 16, axis=1)])
+
+
+def _q6_exact_consume(dec: DecimalQ6):
+    """Each row group's exact partial, a Python int in units of
+    10**-``dec.scale``: the block sums come to the host and combine
+    there.  With the recorder on, the whole reduce is an ``exact_sum``
+    span with ``rows`` and ``blocks``."""
+
+    def consume(acc, rg_index, cols):
+        t0 = time.perf_counter()
+        ship, disc, qty, price = (_exact_operand(cols[c], c)
+                                  for c in Q6_COLUMNS)
+        blocks = _q6_exact_blocks(ship, disc, qty, price,
+                                  disc_lo=dec.disc_lo, disc_hi=dec.disc_hi,
+                                  qty_max=dec.qty_max)
+        sums = _to_host(np.asarray, blocks, "q6_blocks").astype(np.int64)
+        part = int(sums[0].sum()) + (int(sums[1].sum()) << 16)
+        tr = trace_mod.active()
+        if tr is not None:
+            tr.complete("exact_sum", "consume", t0, time.perf_counter(),
+                        rows=int(ship.shape[0]), blocks=int(sums.shape[1]))
+        return part if acc is None else acc + part
+
+    return consume
+
+
+def _schema_of(source):
+    """The file schema of a scanner, or of a dataset's first fragment
+    (None for a dataset with no fragments)."""
+    if not _is_dataset(source):
+        return source.meta.schema
+    if not source.fragments:
+        return None
+    from repro.core.reader import read_footer
+    return read_footer(source.fragment_path(source.fragments[0])).schema
+
+
 def q6(scanner: Scanner, overlapped: bool = True, use_kernel: bool = False,
        prune: bool = True, prepare_plan: bool = False, depth: int = 2,
        decode_workers: int | None = None, service=None,
@@ -216,12 +345,36 @@ def q6(scanner: Scanner, overlapped: bool = True, use_kernel: bool = False,
     a ScanService tenant (weighted fair scheduling + admission,
     DESIGN.md §11); ``result_cache`` (dataset runs only) is a
     FragmentResultCache — repeated identical Q6 runs answer unchanged
-    fragments from cached partials, invalidated on manifest swap."""
+    fragments from cached partials, invalidated on manifest swap.
+
+    Where the schema gives discount, quantity and price as DECIMAL the
+    answer is an exact ``decimal.Decimal`` (``q6_decimal``): the row
+    groups run the scaled-integer consume whatever ``fused`` says (no
+    FusedSpec is attached: the fused aggregate is float32), and
+    ``use_kernel=True`` raises.  Otherwise it is a float, as before."""
     fused = _resolve_fused(fused)
-    spec = q6_fused_spec("reference" if fused == "reference"
-                         else "fused") if fused else None
-    consume = (_q6_consume_fused(use_kernel) if spec is not None
-               else _q6_consume(use_kernel))
+    schema = _schema_of(scanner)
+    dec = q6_decimal(schema) if schema is not None else None
+    if dec is None:
+        spec = q6_fused_spec("reference" if fused == "reference"
+                             else "fused") if fused else None
+        consume = (_q6_consume_fused(use_kernel) if spec is not None
+                   else _q6_consume(use_kernel))
+    else:
+        if use_kernel:
+            raise ValueError("use_kernel=True sums in float32 "
+                             "(kernels/filter_agg.py); Q6 over DECIMAL "
+                             "operands is served exactly without it")
+        reg = trace_mod.registry()
+        reg.counter_inc("decimal_exact_queries")
+        if (fused or (open_opts or {}).get("fused_spec") is not None
+                or getattr(scanner, "fused_spec", None) is not None):
+            reg.counter_inc("fused_declined_decimal")
+        spec, consume = None, _q6_exact_consume(dec)
+
+    def answer(acc):
+        return (acc or 0.0) if dec is None else dec.answer(acc or 0)
+
     if _is_dataset(scanner):
         if not overlapped:
             raise ValueError("dataset runs are always sharded/overlapped; "
@@ -233,6 +386,13 @@ def q6(scanner: Scanner, overlapped: bool = True, use_kernel: bool = False,
             predicate_stats=q6_rg_stats_predicate if prune else None)
         if spec is not None:
             open_opts = dict(open_opts or {}, fused_spec=spec)
+        if dec is not None:
+            open_opts = {k: v for k, v in (open_opts or {}).items()
+                         if k != "fused_spec"}
+            for frag in plan.fragments:
+                _check_products_fit(
+                    dec, frag.column_stats.get("l_extendedprice"),
+                    frag.path)
         if devices is not None:
             from repro.dataset.executor import run_distributed_scan
             acc, report = run_distributed_scan(
@@ -240,8 +400,9 @@ def q6(scanner: Scanner, overlapped: bool = True, use_kernel: bool = False,
                 devices=devices, depth=depth,
                 decode_workers=decode_workers, open_opts=open_opts,
                 trace=trace)
-            return (acc or 0.0), report
-        fp = (f"q6:{'fused' if spec is not None else 'unfused'}:"
+            return answer(acc), report
+        fp = (f"q6:exact:p{int(prune)}" if dec is not None else
+              f"q6:{'fused' if spec is not None else 'unfused'}:"
               f"{'ref' if fused == 'reference' else 'opt'}:"
               f"k{int(use_kernel)}:p{int(prune)}")
         acc, report = run_dataset_scan(
@@ -249,10 +410,18 @@ def q6(scanner: Scanner, overlapped: bool = True, use_kernel: bool = False,
             window=window, depth=depth, decode_workers=decode_workers,
             service=service, open_opts=open_opts, trace=trace,
             tenant=tenant, result_cache=result_cache, fingerprint=fp)
-        return (acc or 0.0), report
+        return answer(acc), report
     if spec is not None and scanner.planner is not None \
             and scanner.fused_spec != spec:
         scanner.enable_fused(spec)
+    if dec is not None:
+        if scanner.fused_spec is not None:
+            scanner.enable_fused(None)
+        for i in scanner.plan(q6_rg_stats_predicate if prune else None):
+            _check_products_fit(
+                dec, scanner.meta.row_groups[i].column(
+                    "l_extendedprice").stats,
+                f"{scanner.path} row group {i}")
     if prepare_plan:
         scanner.prepare_plans(
             predicate_stats=q6_rg_stats_predicate if prune else None)
@@ -266,7 +435,7 @@ def q6(scanner: Scanner, overlapped: bool = True, use_kernel: bool = False,
                          predicate_stats=(q6_rg_stats_predicate
                                           if prune else None),
                          trace=trace)
-    return (acc or 0.0), report
+    return answer(acc), report
 
 
 def q6_reference(tables: dict[str, np.ndarray]) -> float:
@@ -278,6 +447,18 @@ def q6_reference(tables: dict[str, np.ndarray]) -> float:
          & (qty < 24))
     return float(np.sum(price[m].astype(np.float64)
                         * disc[m].astype(np.float64)))
+
+
+def q6_decimal_reference(tables: dict[str, np.ndarray]) -> decimal.Decimal:
+    """Numpy oracle over DECIMAL(15,2) cents (int64): discount 5 to 7
+    cents, quantity under 2,400 cents; the products summed as Python
+    ints, the answer exact at scale 4."""
+    ship, disc = tables["l_shipdate"], tables["l_discount"]
+    qty, price = tables["l_quantity"], tables["l_extendedprice"]
+    m = ((ship >= D_1994_01_01) & (ship < D_1995_01_01)
+         & (disc >= 5) & (disc <= 7) & (qty < 2400))
+    units = sum(map(operator.mul, price[m].tolist(), disc[m].tolist()))
+    return decimal.Decimal(units) / 10**4
 
 
 # ---------------------------------------------------------------------------

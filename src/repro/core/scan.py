@@ -267,8 +267,9 @@ class Scanner:
         self._timeouts = 0
 
     def enable_fused(self, spec) -> None:
-        """Attach a FusedSpec to an already-open scanner (rebinds the
-        planner — fused and unfused scans never share stage-A plans)."""
+        """Attach a FusedSpec to an already-open scanner, or detach it
+        with None (rebinds the planner — fused and unfused scans never
+        share stage-A plans)."""
         if self.planner is None:
             raise ValueError("fused scans require use_plan=True")
         self.fused_spec = spec

@@ -52,17 +52,13 @@ class StringColumn:
         return StringColumn(offsets, payload)
 
     def take(self, idx: np.ndarray) -> "StringColumn":
+        idx = np.asarray(idx, dtype=np.int64)
         lens = self.lengths()[idx]
         offsets = np.zeros(len(idx) + 1, dtype=np.int64)
         np.cumsum(lens, out=offsets[1:])
-        out = np.empty(int(offsets[-1]), dtype=np.uint8)
-        src_off = self.offsets
-        pos = 0
-        for j, i in enumerate(idx):
-            a, b = int(src_off[i]), int(src_off[i + 1])
-            out[pos:pos + (b - a)] = self.payload[a:b]
-            pos += b - a
-        return StringColumn(offsets, out)
+        src = (np.repeat(self.offsets[idx] - offsets[:-1], lens)
+               + np.arange(offsets[-1]))
+        return StringColumn(offsets, self.payload[src])
 
     def slice(self, start: int, stop: int) -> "StringColumn":
         off = self.offsets[start:stop + 1]

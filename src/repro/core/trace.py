@@ -59,7 +59,9 @@ Event vocabulary (``tools/trace_report.py`` buckets on these):
   cat "decode"    open, decompress (phase 1), transition, decode
                   (phase 2), fused (phase 3), finalize, decode_rg
                   (monolithic inline/blocking decode)
-  cat "consume"   consume (per-RG reducer on the caller's thread)
+  cat "consume"   consume (per-RG reducer on the caller's thread),
+                  exact_sum (Q6's exact DECIMAL reduce of one row group:
+                  args.rows, args.blocks)
   cat "scan"      scan (whole-run span), dataset_scan, distributed_scan
   cat "fragment"  fragment (per-attempt), shard_assign, steal,
                   quarantine
@@ -89,7 +91,11 @@ tenant.  The registry's tenancy surface: counters
 ``scheduler.admission_queued``, ``scheduler.slo_boosts``,
 ``result_cache.{hits,misses,evictions,invalidated}``, and one
 ``scheduler.tenant_depth.<name>`` gauge per tenant (current active
-scans — the per-tenant queue depth).
+scans — the per-tenant queue depth).  Exact decimals: counters
+``decimal_exact_queries`` and ``fused_declined_decimal`` (per Q6 over
+DECIMAL operands, and per such query that asked for fusion), and
+``int64_narrowed_chunks`` (per INT64 chunk decoded on the device as
+int32, while the recorder is on).
 """
 
 from __future__ import annotations

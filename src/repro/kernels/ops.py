@@ -7,7 +7,8 @@ page in parallel (grid = page count — Insight 1).
 
 Dispatch rules (documented fallbacks, DESIGN.md §2):
   * numeric int32/float32 payloads decode on device;
-  * int64 pages whose chunk stats fit int32 are narrowed, otherwise host;
+  * int64 pages whose chunk stats fit int32 are narrowed (PLAIN,
+    dictionary, DELTA and RLE alike), otherwise host;
   * strings and float64 decode on the host path;
   * gzip chunks are host-decompressed first (no TPU LZ77); cascade chunks
     are decompressed on-device by cascade_decode_pages.
@@ -23,6 +24,7 @@ from collections.abc import Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.core.compression import (Codec, cascade_manifest, decompress,
                                     verify_page)
 from repro.core.encodings import (Encoding, build_delta_manifest,
@@ -51,6 +53,14 @@ _fallback_lock = threading.Lock()
 def host_fallback_counts() -> Counter:
     with _fallback_lock:
         return Counter(_host_fallbacks)
+
+
+def count_int64_narrowed() -> None:
+    """One INT64 chunk decoded on the device as int32: the registry's
+    ``int64_narrowed_chunks``, counted while the recorder is on (a
+    per-chunk count)."""
+    if trace.active() is not None:
+        trace.registry().counter_inc("int64_narrowed_chunks")
 
 
 @dataclasses.dataclass
@@ -167,7 +177,13 @@ def rle_group_arrays(pages_runs: Sequence[tuple[np.ndarray, np.ndarray]]
 # per-encoding device decoders (per-chunk reference path)
 # ---------------------------------------------------------------------------
 
-def _decode_plain_device(pages, field):
+def _decode_plain_device(chunk, pages, field):
+    if field.physical == PhysicalType.INT64:
+        if not _stats_fit_int32(chunk):
+            return None
+        parts = [np.frombuffer(p, dtype=np.int64, count=pm.n_values)
+                 .astype(np.int32) for pm, p in pages]
+        return jnp.asarray(np.concatenate(parts))
     dt = {PhysicalType.INT32: np.int32, PhysicalType.FLOAT: np.float32,
           PhysicalType.BOOLEAN: np.uint8}.get(field.physical)
     if dt is None:
@@ -253,7 +269,7 @@ def _decode_bss_device(chunk, field, pages):
 
 
 _DEVICE_DECODERS = {
-    Encoding.PLAIN: lambda c, f, d, p: _decode_plain_device(p, f),
+    Encoding.PLAIN: lambda c, f, d, p: _decode_plain_device(c, p, f),
     Encoding.RLE_DICTIONARY: _decode_dict_device,
     Encoding.DELTA_BINARY_PACKED:
         lambda c, f, d, p: _decode_delta_device(c, f, p),
@@ -364,6 +380,8 @@ def decode_chunk(chunk: ChunkMeta, field: Field, raw: bytes,
         if dec is not None:
             arr = dec(chunk, field, dict_payload, pages)
     on_device = arr is not None
+    if on_device and field.physical == PhysicalType.INT64:
+        count_int64_narrowed()
     if use_kernels and arr is None:
         with _fallback_lock:
             _host_fallbacks[chunk.name] += 1

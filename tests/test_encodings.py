@@ -153,3 +153,72 @@ def test_string_encodings_property(values, encoding):
     col = StringColumn.from_pylist(values)
     out = _roundtrip_page(encoding, col, Field("s", PhysicalType.BYTE_ARRAY))
     assert out.to_pylist() == col.to_pylist()
+
+
+def _loop_unique_strings(values):
+    """The per-value dictionary build the writer used before: first
+    occurrence order, one Python dict lookup per row."""
+    table, order = {}, []
+    codes = np.empty(len(values), dtype=np.int64)
+    for i, b in enumerate(values.to_pylist()):
+        code = table.get(b)
+        if code is None:
+            code = table[b] = len(order)
+            order.append(b)
+        codes[i] = code
+    return StringColumn.from_pylist(order), codes
+
+
+def _seeded_strings(seed, n):
+    """Repeated TPC-H-like values, empty strings, values that differ only
+    by trailing NULs or past the first 8 bytes, and random bytes."""
+    rng = np.random.default_rng(seed)
+    pool = [b"", b"\x00", b"A", b"A\x00", b"MAIL", b"DELIVER IN PERSON",
+            b"DELIVER IN PERSOM", b"12345678", b"12345678\x00",
+            b"123456789", b"x" * 40]
+    vals = [pool[i] for i in rng.integers(0, len(pool), n)]
+    vals += [rng.integers(0, 256, int(k)).astype(np.uint8).tobytes()
+             for k in rng.integers(0, 20, n // 10)]
+    rng.shuffle(vals)
+    return StringColumn.from_pylist(vals)
+
+
+@pytest.mark.parametrize("seed,n", [(0, 0), (1, 1), (2, 7), (3, 2_000),
+                                    (4, 70_000)])
+def test_string_dictionary_matches_the_loop(seed, n):
+    values = _seeded_strings(seed, n)
+    want_dict, want_codes = _loop_unique_strings(values)
+    got_dict, got_codes = enc._unique_with_codes(values)
+    npt.assert_array_equal(got_dict.offsets, want_dict.offsets)
+    npt.assert_array_equal(got_dict.payload, want_dict.payload)
+    npt.assert_array_equal(got_codes, want_codes)
+
+
+def test_string_dictionary_chunk_bytes_match_the_loop(monkeypatch,
+                                                      tmp_path):
+    """A dictionary-encoded string chunk, and a whole file written with
+    the writer's smallest-wins choice (dictionary here), are byte for
+    byte what the loop wrote."""
+    from repro.core.config import ACCELERATOR_OPTIMIZED
+    from repro.core.table import Table
+    from repro.core.writer import write_table
+    values = _seeded_strings(5, 30_000)
+    field = Field("s", PhysicalType.BYTE_ARRAY)
+    slices = [(0, 10_000), (10_000, 25_000), (25_000, len(values))]
+    cfg = ACCELERATOR_OPTIMIZED.replace(rows_per_rg=20_000,
+                                        target_pages_per_chunk=3)
+
+    def written(name):
+        chunk = enc.encode_dict_chunk(values, field, slices, 1.0)
+        meta = write_table(Table({"s": values}), str(tmp_path / name), cfg)
+        assert {c.encoding for rg in meta.row_groups for c in rg.columns} \
+            == {enc.Encoding.RLE_DICTIONARY}
+        return chunk, (tmp_path / name).read_bytes()
+
+    got_chunk, got_file = written("vectorised.tab")
+    monkeypatch.setattr(enc, "_unique_strings", _loop_unique_strings)
+    want_chunk, want_file = written("loop.tab")
+    assert got_chunk.dict_page.payload == want_chunk.dict_page.payload
+    assert [p.payload for p in got_chunk.pages] == \
+        [p.payload for p in want_chunk.pages]
+    assert got_file == want_file

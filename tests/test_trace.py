@@ -702,3 +702,53 @@ def test_stage_counts_only_the_bytes_it_moves():
     # the cached dictionary already on the device moves nothing
     assert [s.args["bytes"] for s in stage] == [1024 + 32]
     assert pack[0].ts + pack[0].dur <= stage[0].ts + 1e-9
+
+
+# -- the exact DECIMAL reduce --------------------------------------------------
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_decimal_q6_spans_each_scanned_row_group_and_counts(tmp_path,
+                                                           fused):
+    """Q6 over INT64 DECIMAL cents, traced: one ``exact_sum`` span with
+    ``rows`` and ``blocks`` per row group the zone maps keep, the exact
+    query counted, each INT64 chunk counted as narrowed on the device,
+    and a fused request counted as declined."""
+    from repro.core.query import EXACT_BLOCK
+    from repro.core.schema import Field, LogicalType, PhysicalType, Schema
+
+    def dec(name):
+        return Field(name, PhysicalType.INT64, LogicalType.DECIMAL, 2)
+
+    n = 4_500
+    rng = np.random.default_rng(16)
+    tbl = Table({
+        # sorted dates: the first row group ends before 1994 and is pruned
+        "l_shipdate": np.sort(rng.integers(300, 1500, n)).astype(np.int32),
+        "l_discount": rng.integers(0, 11, n).astype(np.int64),
+        "l_quantity": rng.integers(1, 51, n).astype(np.int64) * 100,
+        "l_extendedprice": rng.integers(90_000, 10_494_951,
+                                        n).astype(np.int64)},
+        Schema([Field("l_shipdate", PhysicalType.INT32, LogicalType.DATE),
+                dec("l_discount"), dec("l_quantity"),
+                dec("l_extendedprice")]))
+    path = str(tmp_path / "dec.tab")
+    meta = write_table(tbl, path, CFG)
+    kept = [rg for rg in meta.row_groups
+            if rg.column("l_shipdate").stats["max"] >= 731
+            and rg.column("l_shipdate").stats["min"] < 1096]
+    assert 0 < len(kept) < len(meta.row_groups)
+    sc = open_scanner(path, columns=list(Q6_COLUMNS),
+                      decode_backend="pallas")
+    tr = trace.enable()
+    q6(sc, fused=fused)
+    spans = _spans(tr, "exact_sum")
+    assert len(spans) == len(kept)
+    assert all(e.cat == "consume" for e in spans)
+    assert sorted(e.args["rows"] for e in spans) == \
+        sorted(rg.n_rows for rg in kept)
+    assert all(e.args["blocks"] == -(-e.args["rows"] // EXACT_BLOCK)
+               for e in spans)
+    counters = trace.registry().snapshot()["counters"]
+    assert counters["decimal_exact_queries"] == 1
+    assert counters["int64_narrowed_chunks"] == 3 * len(kept)
+    assert counters.get("fused_declined_decimal", 0) == int(fused)
