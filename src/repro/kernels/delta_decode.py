@@ -1,14 +1,26 @@
 """Pallas kernel: DELTA_BINARY_PACKED page decode (V2).
 
 grid = (num_pages,).  Each grid step decodes one page: a fori_loop walks the
-page's 1024-value blocks — one (8, 128) tile each, two rows per miniblock —
-carrying the running prefix as a tile.
+page's 1024-value blocks two at a time — one (8, 128) tile each, two rows
+per miniblock — carrying the running prefix as a tile.
 
-Miniblock widths are per-miniblock runtime values, so the unpack reads the
-page payload as 128-word lines: a block's words span a few lines starting
-at a dynamic row (``pl.ds`` on the sublane axis), and every tile lane picks
-its word out of those lines with one in-row lane gather per line.  After
-min_delta, an exact int32 log-step scan (kernels/common.py) turns deltas
+Miniblock widths (≤ 32 on the device path) are runtime values read from
+SMEM, so a tile row's words sit at a dynamic offset of the page payload,
+which the kernel holds as 128-word lines.  A row's four packing groups are
+``4·w ≤ 128`` consecutive words, so they lie within two lines.  Per block:
+
+* fetch: two single-line loads per row (16 in all), then one lane gather
+  from each of the two puts word ``j`` of group ``g`` in lane ``(g, j)``:
+  every word of the block is read once, whatever its width (lanes
+  ``j ≥ w`` are zeroed);
+* unpack: lane ``(g, j)`` now holds bit ``j`` of the group's 32 values
+  (bit-transposed packing, core/bitpack.py), so a 32×32 bit transpose of
+  each 32-lane segment — 5 butterfly steps of one lane gather and a few
+  shifts, masks and selects — leaves value ``j`` in lane ``(g, j)``.
+
+So a block costs 16 loads, 7 lane gathers and ~40 vector ops at every
+width ``w`` from 1 to 32, with no loop over bits or lines.  After
+min_delta an exact int32 log-step scan (kernels/common.py) turns deltas
 into values.  Per-miniblock offsets/widths, min_deltas and the first value
 ride in SMEM, one row per page.
 
@@ -27,11 +39,18 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import (BLOCK_VALUES, MB_GROUPS, MINIBLOCKS, ROW,
-                                  cdiv, count_launch, inclusive_scan_tile,
+from repro.kernels.common import (BLOCK_VALUES, LANES, MINIBLOCKS, ROW, cdiv,
+                                  count_launch, inclusive_scan_tile,
                                   interpret_default, smem_pages, to_rows)
 
 TAIL = 128  # lane-aligned tail block holding the final value
+
+
+# the 32x32 bit transpose: at step ``s`` a lane with bit ``s`` of its index
+# clear swaps its bits selected by ``mask << s`` with its partner's (lane
+# ``+ s``) bits selected by ``mask``
+_TRANSPOSE_STEPS = ((16, 0x0000FFFF), (8, 0x00FF00FF), (4, 0x0F0F0F0F),
+                    (2, 0x33333333), (1, 0x55555555))
 
 
 def _kernel(sc_ref, payload_ref, out_ref, *, n_blocks: int, n_lines: int):
@@ -41,43 +60,45 @@ def _kernel(sc_ref, payload_ref, out_ref, *, n_blocks: int, n_lines: int):
     shape = (8, ROW)
     sub = lax.broadcasted_iota(jnp.int32, shape, 0)
     lane = lax.broadcasted_iota(jnp.int32, shape, 1)
-    mb_of_row = sub >> 1                      # 2 rows (256 values) per mb
-    group = (sub & 1) * 4 + (lane >> 5)       # packing group within the mb
-    j = (lane & 31).astype(jnp.uint32)
+    group = lane >> 5                         # packing group within the row
+    j = lane & (LANES - 1)
 
     def block(b, carry):
-        offs = [sc_ref[b * MINIBLOCKS + m] for m in range(MINIBLOCKS)]
-        wids = [sc_ref[n_mb + b * MINIBLOCKS + m] for m in range(MINIBLOCKS)]
-        line0 = offs[0] // ROW
-        end = offs[-1] + MB_GROUPS * wids[-1]          # offsets ascend
-        lines = jnp.clip((end - line0 * ROW + ROW - 1) // ROW, 0,
-                         n_lines - line0)
-        off_v = jnp.zeros(shape, jnp.int32)
+        # each tile row holds 4 packing groups of one miniblock (2 rows per
+        # miniblock): 4·w ≤ 128 words from `start`, within 2 lines
+        words_lo = jnp.zeros(shape, jnp.uint32)
+        words_hi = jnp.zeros(shape, jnp.uint32)
+        start_v = jnp.zeros(shape, jnp.int32)
         w_v = jnp.zeros(shape, jnp.int32)
-        for m in range(MINIBLOCKS):
-            off_v = jnp.where(mb_of_row == m, offs[m], off_v)
-            w_v = jnp.where(mb_of_row == m, wids[m], w_v)
-        base = off_v - line0 * ROW + group * w_v
-        w_max = jnp.maximum(jnp.maximum(wids[0], wids[1]),
-                            jnp.maximum(wids[2], wids[3]))
-
-        def bit(k, rel):
-            idx = base + k
-            line_of, lane_of = idx >> 7, idx & (ROW - 1)
-
-            def pick(t, word):
-                line = payload_ref[0, pl.ds(line0 + t, 1), :]
-                got = jnp.take_along_axis(jnp.broadcast_to(line, shape),
-                                          lane_of, axis=1)
-                return jnp.where(line_of == t, got, word)
-
-            word = lax.fori_loop(0, lines, pick,
-                                 jnp.zeros(shape, jnp.uint32))
-            b_k = ((word >> j) & jnp.uint32(1)) << k.astype(jnp.uint32)
-            return rel | jnp.where(k < w_v, b_k, jnp.uint32(0))
-
-        rel = lax.fori_loop(0, w_max, bit, jnp.zeros(shape, jnp.uint32))
-        deltas = rel.astype(jnp.int32) + sc_ref[2 * n_mb + b]
+        for r in range(8):
+            m = b * MINIBLOCKS + (r >> 1)
+            w = sc_ref[n_mb + m]
+            start = sc_ref[m] + (r & 1) * 4 * w
+            # only a width-0 row can start on the page's last line
+            line = jnp.minimum(start // ROW, n_lines - 2)
+            row = sub == r
+            words_lo = jnp.where(row, jnp.broadcast_to(
+                payload_ref[0, pl.ds(line, 1), :], shape), words_lo)
+            words_hi = jnp.where(row, jnp.broadcast_to(
+                payload_ref[0, pl.ds(line + 1, 1), :], shape), words_hi)
+            start_v = jnp.where(row, start - line * ROW, start_v)
+            w_v = jnp.where(row, w, w_v)
+        # lane (g, j) takes word j of group g: bit j of the group's values
+        pos = start_v + group * w_v + j
+        idx = pos & (ROW - 1)
+        bits = jnp.where(pos < ROW,
+                         jnp.take_along_axis(words_lo, idx, axis=1),
+                         jnp.take_along_axis(words_hi, idx, axis=1))
+        bits = jnp.where(j < w_v, bits, jnp.uint32(0))
+        # transpose each 32-lane segment's 32x32 bits: lane (g, j) ← value j
+        for s, mask in _TRANSPOSE_STEPS:
+            low = (j & s) == 0
+            partner = jnp.take_along_axis(bits, lane ^ s, axis=1)
+            lo_word = jnp.where(low, bits, partner)
+            hi_word = jnp.where(low, partner, bits)
+            t = ((lo_word >> jnp.uint32(s)) ^ hi_word) & jnp.uint32(mask)
+            bits = bits ^ jnp.where(low, t << jnp.uint32(s), t)
+        deltas = bits.astype(jnp.int32) + sc_ref[2 * n_mb + b]
         incl = inclusive_scan_tile(deltas)
         row0 = pl.multiple_of(b * 8, 8)
         out_ref[0, pl.ds(row0, 8), :] = carry + (incl - deltas)
@@ -88,9 +109,16 @@ def _kernel(sc_ref, payload_ref, out_ref, *, n_blocks: int, n_lines: int):
                                     axis=0)
         return carry + total
 
+    # two blocks a step: the second block's fetch and transpose do not wait
+    # on the first block's carry, so the scheduler overlaps the two
+    def pair(i, carry):
+        return block(2 * i + 1, block(2 * i, carry))
+
     first = sc_ref[2 * n_mb + n_blocks]
-    last = lax.fori_loop(0, n_blocks, block,
+    last = lax.fori_loop(0, n_blocks // 2, pair,
                          jnp.full(shape, first, jnp.int32))
+    if n_blocks % 2:
+        last = block(n_blocks - 1, last)
     # deltas count n-1: the final value (index n_blocks*1024) lands in the
     # tail lane block
     out_ref[0, pl.ds(n_blocks * 8, 1), :] = last[0:1, :]
@@ -128,7 +156,7 @@ def _delta_decode_pages_jit(payload, mb_off, mb_width, min_delta,
         [mb_off.astype(jnp.int32), mb_width.astype(jnp.int32),
          min_delta.astype(jnp.int32), first_value.astype(jnp.int32)],
         axis=1))
-    n_lines = cdiv(n_words, ROW) + 1
+    n_lines = max(cdiv(n_words, ROW), 1) + 1
     n_rows = n_blocks * 8 + 1
     out = pl.pallas_call(
         functools.partial(_kernel, n_blocks=n_blocks, n_lines=n_lines),

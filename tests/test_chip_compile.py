@@ -6,8 +6,9 @@ and VMEM refusals surface on any machine.  Widths are those the TPC-H
 Q6/Q12 scans produce under ACCELERATOR_OPTIMIZED (10M-row row groups, 100
 pages per chunk, so 100k values per page): date dictionaries of ~2.5k
 entries at width 12, small flag dictionaries at widths 3-6, DELTA
-orderkeys at 98 blocks per page (128 after the planner's pow2 class
-padding), and pow2-padded 131072-lane fused pages.
+orderkeys at width 1 and DELTA INT64 extendedprice cents at about 25 bits,
+both at 98 blocks per page (128 after the planner's pow2 class padding),
+and pow2-padded 131072-lane fused pages.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and a test worker that loads it
@@ -36,6 +37,7 @@ PAGES = 100               # ACCELERATOR_OPTIMIZED.target_pages_per_chunk
 PAGE_VALUES = 100_000     # 10M-row row group / 100 pages
 DATE_DICT = 2548          # distinct l_receiptdate values in one row group
 GROUPS = -(-PAGE_VALUES // 32)
+PRICE_WORDS = 98 * (3 + 32 * 25)  # 98 blocks: header + 4 miniblocks at w 25
 FUSED_P = 131_072         # core/fused.py pads pages to a power of two
 
 
@@ -111,6 +113,13 @@ def _cases(s):
                             a((PAGES, n_blocks), jnp.int32),
                             a((PAGES, 1), jnp.int32)],
                            dict(n_blocks=n_blocks)),
+        "delta_price": (_delta_decode_pages_jit,
+                        [a((PAGES, PRICE_WORDS), jnp.uint32),
+                         a((PAGES, n_blocks * 4), jnp.int32),
+                         a((PAGES, n_blocks * 4), jnp.int32),
+                         a((PAGES, n_blocks), jnp.int32),
+                         a((PAGES, 1), jnp.int32)],
+                        dict(n_blocks=n_blocks)),
         "rle": (_rle_decode_pages_jit,
                 [a((PAGES, 8192), jnp.int32), a((PAGES, 8192), jnp.int32)],
                 dict(n_out=131_072)),
@@ -141,8 +150,8 @@ def _cases(s):
 
 
 KERNELS = ["dict_shared_date", "dict_shared_flag", "dict_multi_dates",
-           "delta_orderkey", "rle", "fused_q6", "bss", "cascade",
-           "filter_agg"]
+           "delta_orderkey", "delta_price", "rle", "fused_q6", "bss",
+           "cascade", "filter_agg"]
 
 
 @pytest.mark.parametrize("name", KERNELS)

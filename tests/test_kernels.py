@@ -51,41 +51,70 @@ def test_dict_decode_sweep(n_dict, dtype):
     npt.assert_array_equal(np.asarray(out)[:, :224], dictionary[codes])
 
 
-@pytest.mark.parametrize("n_values", [1025, 4096, 10_000])
+def _delta_pages_of_width(width: int, rng) -> list[np.ndarray]:
+    """Three INT64 pages of 2900, 1500 and 700 values (3, 2 and 1 blocks)
+    whose full blocks hold miniblocks of mixed widths, the widest exactly
+    ``width``: each block's deltas are ``base + rel`` with ``base <= 0``,
+    a zero ``rel`` in every miniblock and bit ``w - 1`` set in one."""
+    pages = []
+    for n in (2900, 1500, 700):
+        n_blocks = -(-(n - 1) // 1024)
+        rel = np.zeros((n_blocks, 4, 256), np.int64)
+        base = rng.integers(-1000, 1, (n_blocks, 1, 1))
+        for b in range(n_blocks):
+            widths = rng.permutation(
+                [width, width // 2, rng.integers(0, width + 1), 0])
+            for m, w in enumerate(widths):
+                if w:
+                    rel[b, m] = rng.integers(0, 2 ** int(w), 256)
+                    rel[b, m, 0] = 2 ** (int(w) - 1)
+                    rel[b, m, 1] = 0
+        deltas = (base + rel).reshape(-1)[:n - 1]
+        first = rng.integers(-2 ** 20, 2 ** 20)
+        pages.append(first + np.concatenate([[0], np.cumsum(deltas)]))
+    return pages
+
+
+# ints: three INT32 random walks of n_values; "w<k>": the three mixed-width
+# INT64 pages above, widest miniblock k bits, padded to 4 blocks (the
+# planner's pow2 class of 3) as delta_group_arrays pads them
+@pytest.mark.parametrize("n_values", [
+    1025, 4096, 10_000,
+    *(pytest.param(f"w{w}", id=f"w{w}") for w in (1, 2, 7, 13, 16, 25, 31,
+                                                   32))])
 def test_delta_decode_sweep(n_values):
     from repro.core.encodings import (build_delta_manifest,
-                                      encode_delta_page)
+                                      decode_delta_page, encode_delta_page)
     from repro.core.schema import Field, PhysicalType
-    rng = np.random.default_rng(n_values)
-    pages = [np.cumsum(rng.integers(-3, 50, n_values)).astype(np.int32)
-             for _ in range(3)]
-    encoded = [encode_delta_page(p, Field("c", PhysicalType.INT32))
-               for p in pages]
+    from repro.kernels.ops import delta_group_arrays
+    width = int(n_values[1:]) if isinstance(n_values, str) else 0
+    if width:
+        field = Field("c", PhysicalType.INT64)
+        pages = _delta_pages_of_width(width, np.random.default_rng(width))
+    else:
+        field = Field("c", PhysicalType.INT32)
+        rng = np.random.default_rng(n_values)
+        pages = [np.cumsum(rng.integers(-3, 50, n_values)).astype(np.int32)
+                 for _ in range(3)]
+    encoded = [encode_delta_page(p, field) for p in pages]
     mans = [build_delta_manifest(e.payload, e.n_values, e.extra)
             for e in encoded]
-    n_blocks = max(m["n_blocks"] for m in mans)
-    n_mb = n_blocks * 4
-
-    def pad2(arrs, w, dt):
-        out = np.zeros((len(arrs), w), dt)
-        for i, a in enumerate(arrs):
-            out[i, :len(a)] = a
-        return out
-
-    payload = pad2([np.frombuffer(e.payload, np.uint32) for e in encoded],
-                   max(len(e.payload) // 4 for e in encoded), np.uint32)
-    mb_off = pad2([m["mb_off"] for m in mans], n_mb, np.int32)
-    mb_w = pad2([m["mb_width"] for m in mans], n_mb, np.int32)
-    mind = pad2([m["min_delta"].astype(np.int32)[:m["n_blocks"]]
-                 for m in mans], n_blocks, np.int32)
-    first = np.array([[m["first_value"]] for m in mans], np.int32)
-    args = [jnp.asarray(x) for x in
-            (payload, mb_off, mb_w, mind, first)]
-    out = delta_decode_pages(*args, n_blocks=n_blocks)
+    n_blocks = 4 if width else max(m["n_blocks"] for m in mans)
+    args = [jnp.asarray(x) for x in delta_group_arrays(
+        mans, [e.payload for e in encoded], n_blocks)]
+    if width:
+        assert width in args[2][0]
+        if width > 1:                      # the writer's least width is 1
+            assert len(set(np.asarray(args[2][0, :4]).tolist())) > 1
+        assert np.asarray(args[1][0, 4:8] % 128).any()   # not 128-aligned
+    out = np.asarray(delta_decode_pages(*args, n_blocks=n_blocks))
     out_ref = ref.delta_decode_pages_ref(*args, n_blocks=n_blocks)
-    npt.assert_array_equal(np.asarray(out), np.asarray(out_ref))
-    for i, p in enumerate(pages):
-        npt.assert_array_equal(np.asarray(out)[i, :n_values], p)
+    npt.assert_array_equal(out, np.asarray(out_ref))
+    for i, (p, e) in enumerate(zip(pages, encoded)):
+        host = decode_delta_page(e.payload, e.n_values, field, e.extra)
+        npt.assert_array_equal(host, p)
+        # the device path is int32: INT64 values wrap modulo 2**32
+        npt.assert_array_equal(out[i, :p.shape[0]], p.astype(np.int32))
 
 
 @pytest.mark.parametrize("max_run", [1, 50, 3000])
